@@ -8,6 +8,9 @@ the paper's reported range (units: seconds, Table II), and evaluate the
 """
 from __future__ import annotations
 
+import os
+import subprocess
+import sys
 
 import numpy as np
 
@@ -73,3 +76,23 @@ def all_plans(pf, cl, wl, cap, slots):
     cluster = ClusterView(capacity=cap, slots_cap=slots)
     return {label: get_policy(name).propose(freqs, cluster)
             for label, name in POLICY_NAMES.items()}
+
+
+def run_cpu_leg(script: str, n_devices: int, timeout: float) -> str:
+    """Run ``tests/md_scripts/<script>`` in a child process on
+    ``n_devices`` placeholder host devices and return its output, which
+    starts with the child's ``platform:`` line. The leg is CPU-only: the
+    child is pinned to ``JAX_PLATFORMS=cpu`` (a parent that has touched JAX
+    holds the chip, and the fake devices exist only on the CPU), so none of
+    its numbers is a chip number."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tests", "md_scripts", script)
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run([sys.executable, path], capture_output=True,
+                          text=True, timeout=timeout, env=env)
+    if proc.returncode != 0 or "ALL OK" not in proc.stdout:
+        raise RuntimeError(
+            f"{script} leg failed:\n{proc.stdout}\n{proc.stderr}")
+    return proc.stdout

@@ -132,6 +132,9 @@ def main() -> None:
     if "--help" in sys.argv or "-h" in sys.argv:
         print(__doc__)
         return
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if "--smoke" in sys.argv:
         out = "BENCH_serving.json"
         trace = "BENCH_trace.json"

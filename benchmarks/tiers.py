@@ -32,12 +32,11 @@ device count once initialized.
 
 from __future__ import annotations
 
-import os
-import subprocess
 import sys
 
 import numpy as np
 
+from benchmarks.common import run_cpu_leg
 from benchmarks.topology import BENCH_PROFILE, build_requests
 from repro.core.policies import ClusterView, PlacementController, get_policy
 from repro.serving.cluster import EdgeCluster
@@ -51,9 +50,8 @@ HOST_SLOTS = (16, 14, 12)
 
 def _sharp_task_profile(name: str, idx: int, pf, seed: int):
     """A sharply skewed per-task activation profile (Zipf a in 2.2-2.8 vs
-    the 0.3-1.6 library default), seeded off ``idx`` instead of
-    ``hash(name)`` so results are bit-identical across *processes* (Python
-    string hashing is randomized per interpreter). Sparse gating tails are
+    the 0.3-1.6 library default), seeded off ``idx`` and ``seed``. Sparse
+    gating tails are
     what makes on-demand-fetch counts residency-sensitive — see
     ``run_leg``."""
     from repro.data.traces import TaskProfile
@@ -178,31 +176,6 @@ def tiers_section(results: dict) -> dict:
     return out
 
 
-def run_runtime_leg(timeout: float = 300.0) -> str:
-    """The runtime-backend leg: real jitted engines over 3 fake CPU
-    devices, tiered modeled budgets. Runs as a subprocess because the
-    parent's JAX is already initialized with one device."""
-    script = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "tests",
-        "md_scripts",
-        "tiers_runtime.py",
-    )
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=3"
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    proc = subprocess.run(
-        [sys.executable, script],
-        capture_output=True,
-        text=True,
-        timeout=timeout,
-        env=env,
-    )
-    if proc.returncode != 0 or "ALL OK" not in proc.stdout:
-        raise RuntimeError(f"runtime tier leg failed:\n{proc.stdout}\n{proc.stderr}")
-    return proc.stdout
-
-
 def smoke(n_requests: int = 40) -> dict:
     """Small CI-gate measurement: the ``metrics.tiers`` document section,
     with the oversized-serving acceptance gates asserted."""
@@ -268,9 +241,9 @@ def main(csv: bool = False):
             print(f"tiers,{name}_mean_latency_s,{r['mean_latency_s']:.6f}")
         print(f"tiers,promotions,{on['tiers']['promotions']}")
     assert on["mean_latency_s"] < off["mean_latency_s"]
-    print("# runtime-backend leg (3 fake devices, subprocess)...")
-    out = run_runtime_leg()
-    print(out.strip().splitlines()[-1])
+    print("# runtime-backend leg (CPU-only: 3 fake host devices, subprocess)")
+    out = run_cpu_leg("tiers_runtime.py", 3, timeout=300.0).strip().splitlines()
+    print(f"{out[0]}\n{out[-1]}")
 
 
 if __name__ == "__main__":

@@ -44,12 +44,11 @@ cannot re-configure the JAX device count once initialized).
 """
 from __future__ import annotations
 
-import os
-import subprocess
 import sys
 
 import numpy as np
 
+from benchmarks.common import run_cpu_leg
 from benchmarks.topology import (BENCH_PROFILE, _historical_stats,
                                  wan_testbed)
 from repro.core.policies import ClusterView, PlacementController, get_policy
@@ -131,26 +130,6 @@ def workload_section(results: dict, spec: WorkloadSpec) -> dict:
     }
 
 
-def run_runtime_leg(timeout: float = 600.0) -> str:
-    """The warmed multi-server runtime-backend leg: the flash-crowd
-    stream against a jitted 3-server ``EdgeCluster("runtime")`` with
-    AOT warmup + SLO-aware scheduling, per-phase goodput. Runs as a
-    subprocess because the parent's JAX is already initialized with one
-    device."""
-    script = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "tests", "md_scripts", "workload_runtime_cluster.py")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=3"
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    proc = subprocess.run([sys.executable, script], capture_output=True,
-                          text=True, timeout=timeout, env=env)
-    if proc.returncode != 0 or "ALL OK" not in proc.stdout:
-        raise RuntimeError(
-            f"runtime workload leg failed:\n{proc.stdout}\n{proc.stderr}")
-    return proc.stdout
-
-
 def smoke(spec: WorkloadSpec = BENCH_SPEC) -> dict:
     """CI-gate measurement: the ``metrics.workload`` document section."""
     results = measure(spec)
@@ -197,11 +176,12 @@ def main(csv: bool = False):
         print(f"workload,sheds,{slo['sheds']}")
     assert slo["goodput_tokens_per_s"] > fifo["goodput_tokens_per_s"], (
         "SLO-aware scheduling should beat blind FIFO on goodput")
-    print("# warmed multi-server runtime-backend leg (3 fake devices, "
-          "subprocess)...")
-    out = run_runtime_leg()
+    print("# warmed multi-server runtime-backend leg (CPU-only: 3 fake "
+          "host devices, subprocess; tick-clock goodput, not chip numbers)")
+    out = run_cpu_leg("workload_runtime_cluster.py", 3, timeout=600.0)
     for line in out.strip().splitlines():
-        if line.startswith(("goodput:", "  phase", "zero-stall", "ALL OK")):
+        if line.startswith(("platform:", "goodput:", "  phase", "zero-stall",
+                            "ALL OK")):
             print(f"  {line}")
 
 

@@ -8,9 +8,16 @@ algorithms exploit."""
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Iterator
 
 import numpy as np
+
+
+def stable_seed(name: str, seed: int) -> int:
+    """Process-independent RNG seed for a named stream: CRC-32 of the key,
+    where Python's ``hash()`` of a string is salted per interpreter."""
+    return zlib.crc32(f"{name}/{seed}".encode())
 
 
 @dataclasses.dataclass
@@ -21,8 +28,7 @@ class TaskTokenSource:
     zipf: float = 1.1
 
     def __post_init__(self):
-        rng = np.random.default_rng(abs(hash((self.name, self.seed)))
-                                    % (2 ** 31))
+        rng = np.random.default_rng(stable_seed(self.name, self.seed))
         V = self.vocab_size
         base = 1.0 / (np.arange(V) + 1.0) ** self.zipf
         self.unigram = base[np.argsort(rng.permutation(V))]

@@ -14,6 +14,8 @@ import dataclasses
 
 import numpy as np
 
+from repro.data.pipeline import stable_seed
+
 # named after the paper's BIG-bench server specialisations + MultiData setup
 BIGBENCH_TASKS = ("abstract_narrative", "arithmetic", "ascii_recognition")
 MULTIDATA_TASKS = ("mmlu_pro", "wikitext", "tako")
@@ -29,7 +31,7 @@ class TaskProfile:
 def make_task_profile(name: str, num_layers: int, num_experts: int,
                       seed: int, skew_lo: float = 0.3,
                       skew_hi: float = 1.6) -> TaskProfile:
-    rng = np.random.default_rng(abs(hash((name, seed))) % (2 ** 31))
+    rng = np.random.default_rng(stable_seed(name, seed))
     probs = np.zeros((num_layers, num_experts))
     for l in range(num_layers):
         # layer-dependent skew (Fig. 3): alternate strongly/weakly skewed

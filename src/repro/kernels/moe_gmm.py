@@ -9,8 +9,12 @@ matmul with the gated nonlinearity fused between the two matmuls, so the
 [C, F] intermediate never touches HBM).
 
 Tiling: bc x bf blocks are MXU-aligned (multiples of 128 whenever the
-problem shape allows); D stays resident per block (<= ~12k works in VMEM:
-x tile bc*D + three weight tiles D*bf/bf*D + fp32 accumulator bc*D).
+problem shape allows); D stays resident per block. ``bf`` is the largest
+multiple of 128 no larger than the requested width that divides F (F = 1408
+for DeepSeek-V2-Lite and 6400 for Phi-3.5-MoE are not multiples of 512), and
+the scoped VMEM limit is raised to what the double-buffered blocks plus the
+fp32 accumulator need: at Mixtral width (D 4096, bf 512) that is ~30 MiB,
+over the compiler's 16 MiB default but well inside v5e's 128 MiB of VMEM.
 """
 from __future__ import annotations
 
@@ -41,6 +45,24 @@ def _kernel(x_ref, w1_ref, w3_ref, w2_ref, o_ref, acc_ref, *, nf: int):
         o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
+def _f_block(F: int, bf: int) -> int:
+    """Largest multiple of 128 <= ``bf`` dividing F (F itself when F is not
+    a multiple of 128: a block may always span the whole dimension)."""
+    if F % 128:
+        return F
+    units = F // 128
+    return 128 * max(u for u in range(1, max(bf // 128, 1) + 1)
+                     if units % u == 0)
+
+
+def _vmem_bytes(bc: int, bf: int, D: int, itemsize: int) -> int:
+    """Scoped VMEM the kernel needs: every in/out block double-buffered
+    (x and out [bc, D], w1/w3 [D, bf], w2 [bf, D]) plus the fp32
+    accumulator."""
+    blocks = (2 * bc * D + 3 * D * bf) * itemsize
+    return 2 * blocks + 4 * bc * D
+
+
 @functools.partial(jax.jit, static_argnames=("bc", "bf", "interpret"))
 def moe_gmm(x, w1, w3, w2, *, bc: int = 128, bf: int = 512,
             interpret: bool | None = None):
@@ -50,8 +72,9 @@ def moe_gmm(x, w1, w3, w2, *, bc: int = 128, bf: int = 512,
     S, C, D = x.shape
     F = w1.shape[-1]
     bc = min(bc, C)
-    bf = min(bf, F)
-    assert C % bc == 0 and F % bf == 0, (C, bc, F, bf)
+    bf = _f_block(F, min(bf, F))
+    assert C % bc == 0, (C, bc)
+    vmem = _vmem_bytes(bc, bf, D, x.dtype.itemsize)
     nf = F // bf
     grid = (S, C // bc, nf)
     return pl.pallas_call(
@@ -66,5 +89,8 @@ def moe_gmm(x, w1, w3, w2, *, bc: int = 128, bf: int = 512,
         out_specs=pl.BlockSpec((1, bc, D), lambda s, c, f: (s, c, 0)),
         out_shape=jax.ShapeDtypeStruct((S, C, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem + (4 << 20)),
         interpret=interpret,
     )(x, w1, w3, w2)

@@ -1,10 +1,17 @@
 """Pallas TPU kernel: fused Mamba-1 selective scan.
 
 Computes y directly from (x, dt, B, C, A, D) without ever materialising the
-[B, T, d, N] state trajectory in HBM — the state h [bd, N] lives in a fp32
-VMEM scratch that persists across the sequential T grid dimension. The decay
+[B, T, d, N] state trajectory in HBM — the state h lives in a fp32 VMEM
+scratch that persists across the sequential T grid dimension. The decay
 a_t = exp(dt_t * A) and input b_t = (dt_t * x_t) B_t are formed on the fly
 per time step inside the kernel (VPU elementwise + small outer products).
+
+Layout: the state is held transposed, ``h [N, bd]``, so channels sit on the
+lanes. The wrapper gives every per-step operand a leading time axis of its
+own (x, dt, y as ``[B, T, 1, d]`` rows; B_t and C_t as ``[B, T, N, 1]``
+columns), so the loop reads step ``t`` with a dynamic index on an untiled
+dimension — the only dynamic indexing Mosaic lowers — and no value is ever
+sliced or transposed inside the kernel.
 
 Grid: (B, d/bd, T/bt) with T innermost/sequential; channels are
 embarrassingly parallel (and shard over the `model` mesh axis one level up).
@@ -27,26 +34,20 @@ def _kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, o_ref, h_ref, *,
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    A = a_ref[...].astype(jnp.float32)            # [bd, N]
-    Dp = d_ref[...].astype(jnp.float32)           # [bd]
-    x = x_ref[0].astype(jnp.float32)              # [bt, bd]
-    dt = dt_ref[0].astype(jnp.float32)            # [bt, bd]
-    Bs = b_ref[0].astype(jnp.float32)             # [bt, N]
-    Cs = c_ref[0].astype(jnp.float32)             # [bt, N]
+    At = a_ref[...].astype(jnp.float32)           # [N, bd]
+    Dp = d_ref[...].astype(jnp.float32)           # [1, bd]
 
-    def step(t, carry):
-        h, ys = carry
-        a_t = jnp.exp(dt[t][:, None] * A)         # [bd, N]
-        b_t = (dt[t] * x[t])[:, None] * Bs[t][None, :]
-        h = a_t * h + b_t
-        y_t = (h * Cs[t][None, :]).sum(-1) + Dp * x[t]
-        return h, ys.at[t].set(y_t)
+    def step(t, h):
+        x_t = x_ref[0, t].astype(jnp.float32)     # [1, bd]
+        dt_t = dt_ref[0, t].astype(jnp.float32)   # [1, bd]
+        b_t = b_ref[0, t].astype(jnp.float32)     # [N, 1]
+        c_t = c_ref[0, t].astype(jnp.float32)     # [N, 1]
+        h = jnp.exp(dt_t * At) * h + (dt_t * x_t) * b_t
+        y_t = (h * c_t).sum(0, keepdims=True) + Dp * x_t
+        o_ref[0, t] = y_t.astype(o_ref.dtype)
+        return h
 
-    h0 = h_ref[...]
-    ys0 = jnp.zeros((bt,) + h0.shape[:1], jnp.float32)
-    h, ys = jax.lax.fori_loop(0, bt, step, (h0, ys0))
-    h_ref[...] = h
-    o_ref[0] = ys.astype(o_ref.dtype)
+    h_ref[...] = jax.lax.fori_loop(0, bt, step, h_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("bd", "bt", "interpret"))
@@ -61,19 +62,25 @@ def ssm_scan(x, dt, Bs, Cs, A, D, *, bd: int = 256, bt: int = 64,
     bt = min(bt, T)
     assert d % bd == 0 and T % bt == 0
     grid = (B, d // bd, T // bt)
-    return pl.pallas_call(
+    row = pl.BlockSpec((1, bt, 1, bd), lambda b, i, t: (b, t, 0, i))
+    col = pl.BlockSpec((1, bt, N, 1), lambda b, i, t: (b, t, 0, 0))
+    y = pl.pallas_call(
         functools.partial(_kernel, bt=bt),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bt, bd), lambda b, i, t: (b, t, i)),  # x
-            pl.BlockSpec((1, bt, bd), lambda b, i, t: (b, t, i)),  # dt
-            pl.BlockSpec((1, bt, N), lambda b, i, t: (b, t, 0)),   # B
-            pl.BlockSpec((1, bt, N), lambda b, i, t: (b, t, 0)),   # C
-            pl.BlockSpec((bd, N), lambda b, i, t: (i, 0)),         # A
-            pl.BlockSpec((bd,), lambda b, i, t: (i,)),             # D
+            row,                                                 # x
+            row,                                                 # dt
+            col,                                                 # B
+            col,                                                 # C
+            pl.BlockSpec((N, bd), lambda b, i, t: (0, i)),       # A^T
+            pl.BlockSpec((1, bd), lambda b, i, t: (0, i)),       # D
         ],
-        out_specs=pl.BlockSpec((1, bt, bd), lambda b, i, t: (b, t, i)),
-        out_shape=jax.ShapeDtypeStruct((B, T, d), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((B, T, 1, d), x.dtype),
+        scratch_shapes=[pltpu.VMEM((N, bd), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt, Bs, Cs, A, D)
+    )(x[:, :, None], dt[:, :, None], Bs[..., None], Cs[..., None], A.T,
+      D[None])
+    return y[:, :, 0]

@@ -1,13 +1,10 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes, with ShapeDtypeStruct inputs (no allocation).
 
-The two lines above MUST stay first: jax locks the device count on first
-initialisation, and the dry-run needs 512 placeholder host devices to build
-the (2, 16, 16) production mesh. Smoke tests and benchmarks do NOT import
-this module — they see the real single CPU device.
+``main()`` appends ``--xla_force_host_platform_device_count=512`` to
+``XLA_FLAGS`` before JAX creates its devices: the dry-run needs 512
+placeholder host devices to build the (2, 16, 16) production mesh. Importing
+the module changes nothing.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch yi-6b --shape train_4k
@@ -16,6 +13,7 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -27,7 +25,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import INPUT_SHAPES, InputShape, ModelConfig
-from repro.configs import get_config
+from repro.configs import ASSIGNED_ARCHS, get_config
 from repro.core.placement import build_ep_placement, dancemoe_placement
 from repro.launch import mesh as mesh_lib
 from repro.launch.roofline import collective_bytes_from_hlo, roofline_report
@@ -37,11 +35,11 @@ from repro.models import transformer as tr
 from repro.optim.adamw import adafactor
 from repro.training.train_loop import make_train_step
 
-ASSIGNED_ARCHS = [
-    "starcoder2-3b", "qwen2-vl-72b", "tinyllama-1.1b", "falcon-mamba-7b",
-    "zamba2-2.7b", "musicgen-large", "command-r-plus-104b",
-    "llama4-maverick-400b-a17b", "yi-6b", "phi3.5-moe-42b-a6.6b",
-]
+def use_host_devices(n: int) -> None:
+    """Ask for ``n`` placeholder host devices by appending to ``XLA_FLAGS``
+    (call before anything creates a JAX device)."""
+    flag = f"--xla_force_host_platform_device_count={n}"
+    os.environ["XLA_FLAGS"] = f"{os.environ.get('XLA_FLAGS', '')} {flag}".strip()
 
 
 def ep_axes_for(cfg: ModelConfig) -> tuple[str, ...]:
@@ -225,7 +223,7 @@ def _analyse(compiled) -> dict:
 def _lower_compile(cfg, shape, mesh, scan_layers=True, **kw):
     fn, kwargs = build_lowerable(cfg, shape, mesh, scan_layers=scan_layers,
                                  **kw)
-    with mesh_lib.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn).lower(**kwargs)
         return lowered.compile()
 
@@ -303,7 +301,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     try:
         # 1) the deliverable: full model, scanned layers, lower + compile
         fn, kwargs = build_lowerable(cfg, shape, mesh, **build_kw)
-        with mesh_lib.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn).lower(**kwargs)
             rec["lower_s"] = round(time.time() - t0, 1)
             t1 = time.time()
@@ -357,6 +355,7 @@ def main():
                     help="apply best-known layout per pair (see EXPERIMENTS"
                          " §Perf); write records to --out")
     args = ap.parse_args()
+    use_host_devices(512)
 
     archs = [args.arch] if args.arch else ASSIGNED_ARCHS
     shapes = [args.shape] if args.shape else list(INPUT_SHAPES)

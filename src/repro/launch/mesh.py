@@ -1,4 +1,4 @@
-"""Production mesh construction.
+"""Production mesh construction and the per-chip peak table.
 
 Target: TPU v5e, 256 chips/pod. Single-pod mesh (16, 16) = ("data",
 "model"); multi-pod (2, 16, 16) = ("pod", "data", "model"). Functions, not
@@ -7,29 +7,11 @@ module constants — importing this module never touches jax device state.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types on the mesh
-    from jax.sharding import AxisType
-    _AXIS_KW = True
-except ImportError:  # older jax: meshes are implicitly Auto
-    AxisType = None
-    _AXIS_KW = False
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if _AXIS_KW:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def set_mesh(mesh):
-    """Version-compat default-mesh context: ``jax.set_mesh`` on jax >= 0.5;
-    on 0.4.x the ``Mesh`` object itself is the (resource-env) context
-    manager and all our sharding is explicit anyway."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -43,8 +25,23 @@ def make_test_mesh(data: int = 1, model: int = 1):
     return _make_mesh((data, model), ("data", "model"))
 
 
-# v5e hardware constants used by the roofline analysis
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link (~per-chip injection)
-CHIPS_PER_POD = 256
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+# HBM, 1,600 Gbit/s inter-chip interconnect per chip over its 4 ICI links.
+PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "hbm_bytes_per_s": 819e9,
+        "ici_link_bytes_per_s": 1600e9 / 8 / 4,
+    },
+}
+TARGET_DEVICE_KIND = "TPU v5 lite"   # the chip the dry-run rooflines model
+
+
+def peaks(device_kind: str) -> dict:
+    """Peak rates of one chip of ``device_kind``; a kind without published
+    peaks in ``PEAKS`` is an error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}"
+                       f" (known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]

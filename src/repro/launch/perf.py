@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Perf-iteration driver (§Perf): lower a (arch, shape) pair under a named
 variant (sharding layout / remat policy / MoE capacity override), derive the
 roofline terms via depth differencing, and append the record to
@@ -20,7 +17,7 @@ from repro.configs import get_config
 from repro.configs.base import INPUT_SHAPES
 from repro.launch import mesh as mesh_lib
 from repro.launch.dryrun import (_analyse, _lower_compile, build_lowerable,
-                                 depth_variant)
+                                 depth_variant, use_host_devices)
 from repro.launch.roofline import roofline_report
 
 VARIANTS = {
@@ -58,7 +55,7 @@ def run_variant(arch: str, shape_name: str, variant: str,
         # full scanned compile: proof + memory analysis
         fn, kwargs = build_lowerable(cfg, shape, mesh, **kw)
         donate = ("cache",) if "cache" in kwargs else ()
-        with mesh_lib.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = jax.jit(fn, donate_argnames=donate).lower(
                 **kwargs).compile()
         if donate:
@@ -112,6 +109,7 @@ def main():
     ap.add_argument("--variant", required=True, choices=list(VARIANTS))
     ap.add_argument("--note", default="")
     args = ap.parse_args()
+    use_host_devices(512)
     rec = run_variant(args.arch, args.shape, args.variant, args.note)
     if rec["ok"]:
         ro = rec["roofline"]

@@ -97,17 +97,18 @@ def roofline_report(rec: dict, cfg, shape, *, n_chips: int) -> dict:
                    reported but double-counts fusion-internal operands.
     collective_s — parsed HLO collective bytes / ICI bw.
     """
-    compute_t = rec["hlo_flops"] / mesh_lib.PEAK_FLOPS
+    peak = mesh_lib.peaks(mesh_lib.TARGET_DEVICE_KIND)
+    compute_t = rec["hlo_flops"] / peak["bf16_flops"]
     passes = 3.0 if shape.kind == "train" else 1.0
     out_bytes = rec.get("output_size_in_bytes", 0)
     if rec.get("donated_cache"):
         out_bytes = max(out_bytes - rec.get("argument_size_in_bytes", 0), 0)
     struct_bytes = (passes * rec.get("argument_size_in_bytes", 0)
                     + out_bytes)
-    memory_t = struct_bytes / mesh_lib.HBM_BW
-    memory_t_hlo = rec["hlo_bytes"] / mesh_lib.HBM_BW
+    memory_t = struct_bytes / peak["hbm_bytes_per_s"]
+    memory_t_hlo = rec["hlo_bytes"] / peak["hbm_bytes_per_s"]
     coll_bytes = rec["collectives"]["total_bytes"]
-    collective_t = coll_bytes / mesh_lib.ICI_BW
+    collective_t = coll_bytes / peak["ici_link_bytes_per_s"]
     terms = {"compute_s": compute_t, "memory_s": memory_t,
              "collective_s": collective_t}
     dominant = max(terms, key=terms.get)
@@ -120,6 +121,6 @@ def roofline_report(rec: dict, cfg, shape, *, n_chips: int) -> dict:
         "model_flops_global": mf,
         "useful_flops_ratio": useful,
         "step_time_lower_bound_s": max(terms.values()),
-        "mfu_bound": mf / max(n_chips * mesh_lib.PEAK_FLOPS
+        "mfu_bound": mf / max(n_chips * peak["bf16_flops"]
                               * max(terms.values()), 1e-30),
     }

@@ -14,23 +14,129 @@ decision).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config, list_configs
 from repro.core.migration import CostModel
 from repro.core.policies import (ClusterView, PlacementController,
                                  get_policy, list_policies)
 from repro.data.pipeline import TaskTokenSource
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models import moe as M
+from repro.models import sharding as sh
 from repro.models import transformer as tr
 from repro.serving.api import Request
 from repro.serving.engine import ServingEngine
 from repro.serving.obs import Tracer
 from repro.serving.runtime import ServingRuntime
+
+
+def ep_capacities(rows: int, top_k: int, n_ep: int) -> tuple[int, int]:
+    """Dropless EP capacities for steps of at most ``rows`` token rows:
+    ``C`` holds one source rank's whole share of rows x ``top_k`` bound for
+    a single destination, and ``C2`` every row of the step, since a slot
+    holds one expert and a row sends it at most one (row, expert) pair."""
+    return max(8, -(-rows // n_ep) * top_k), max(8, rows)
+
+
+def step_rows(*, max_len: int, slots: int, block_size: int,
+              dense_pool: bool = False) -> int:
+    """The most token rows one engine step carries for a runtime of
+    ``slots`` slots: a paged chunk round (slots x block_size) or one
+    request's whole prompt (sequential ``generate()``, under max_len rows);
+    the dense pool prefills up to ``slots`` same-length prompts at once."""
+    if dense_pool:
+        return slots * max_len
+    return max(slots * block_size, max_len)
+
+
+def build_engine(cfg, mesh, *, max_len: int, rows: int,
+                 dtype=jnp.float32) -> ServingEngine:
+    """The serving engine for ``cfg`` on ``mesh``, weights drawn from seed
+    0, for steps of at most ``rows`` token rows (``step_rows``). An MoE
+    model gets the EP layer (one rank per device of the ``model`` axis,
+    uniform boot placement, capacities no step can overflow) and the dense
+    master copy that live migration re-gathers expert slots from. On a
+    multi-device mesh the master's experts and the EP slots are sharded
+    over ``model`` and every other weight is replicated."""
+    key = jax.random.PRNGKey(0)
+    if not cfg.is_moe:
+        rt = tr.Runtime(cfg=cfg, mesh=mesh, dtype=dtype)
+        return ServingEngine(rt=rt, params=tr.init_params(rt, key),
+                             placement=None, max_len=max_len)
+    C, C2 = ep_capacities(rows, cfg.top_k, mesh.shape["model"])
+    spec = M.EPSpec.build(mesh, cfg, ep_axes=("model",), capacity=C,
+                          slot_capacity=C2)
+    rt = tr.Runtime(cfg=cfg, mesh=mesh, moe_impl="ep", ep_spec=spec,
+                    dtype=dtype)
+    params = tr.init_params(dataclasses.replace(rt, moe_impl="dense",
+                                                ep_spec=None), key)
+    experts = None
+    if mesh.size > 1:
+        experts = NamedSharding(mesh, P(None, "model"))
+        place = jax.tree.map(lambda _: NamedSharding(mesh, P()), params)
+        for k, g in params["groups"].items():
+            if "router" in g:
+                for w in ("w1", "w3", "w2"):
+                    place["groups"][k][w] = NamedSharding(mesh, sh._feasible_spec(
+                        mesh, g[w].shape, experts.spec))
+        params = jax.device_put(params, place)
+    _, n_groups = cfg.layer_pattern()
+    pls = tr.stack_placement(
+        M.uniform_placement(spec.n_ep, spec.slots, cfg.num_experts), n_groups)
+    master = params["groups"]
+    params = {**params, "groups": M.regather_ep_groups(
+        master, pls, n_groups, sharding=experts)}
+    return ServingEngine(rt=rt, params=params, placement=pls,
+                         dense_master=master, max_len=max_len)
+
+
+def build_controller(engine: ServingEngine, policy: str,
+                     interval: int) -> PlacementController | None:
+    """The placement control plane of an MoE engine (None for dense
+    models): ``policy`` reviewed every ``interval`` decode rounds."""
+    rt = engine.rt
+    if rt.ep_spec is None:
+        return None
+    cfg = rt.cfg
+    cm = CostModel(expert_bytes=3 * cfg.d_model * cfg.d_ff * 2,
+                   activation_bytes=cfg.d_model * 2, bandwidth=62.5e6,
+                   tokens_per_horizon=1e5)
+    return PlacementController(
+        policy=get_policy(policy), cost=cm,
+        cluster=ClusterView.from_ep_spec(rt.ep_spec, engine.n_groups),
+        interval=interval)
+
+
+def build_server(cfg, *, max_len: int, slots: int, block_size: int = 16,
+                 blocks: int | None = None, dense_pool: bool = False,
+                 prefix_cache: bool = True, warmup: bool = False,
+                 policy: str = "dancemoe", review_rounds: int = 16,
+                 dtype=jnp.float32, tracer: Tracer | None = None):
+    """Engine, placement controller and continuous-batching runtime on a
+    one-device mesh: the one construction path behind the serving CLI and
+    ``chip_smoke.py``. Returns ``(engine, controller, runtime)``."""
+    rows = step_rows(max_len=max_len, slots=slots, block_size=block_size,
+                     dense_pool=dense_pool)
+    engine = build_engine(cfg, make_test_mesh(1, 1), max_len=max_len,
+                          rows=rows, dtype=dtype)
+    controller = build_controller(engine, policy, review_rounds)
+    if controller is not None and tracer is not None:
+        controller.tracer = tracer          # PLACEMENT_REVIEW decisions
+    runtime = ServingRuntime(engine, max_slots=slots, controller=controller,
+                             paged=False if dense_pool else None,
+                             block_size=block_size, n_blocks=blocks,
+                             prefix_cache=prefix_cache, warmup=warmup,
+                             warmup_origins="untagged", tracer=tracer)
+    return engine, controller, runtime
 
 
 def main():
@@ -68,62 +174,33 @@ def main():
                     help="record per-request/system spans and export the "
                     "Chrome-trace JSON here (open at ui.perfetto.dev; "
                     "inspect with tools/trace_view.py)")
-    ap.add_argument("--full", action="store_true")
+    ap.add_argument("--full", action="store_true",
+                    help="published widths in bf16 (default: the reduced "
+                    "float32 variant)")
     args = ap.parse_args()
     if args.shared_prefix and args.shared_prefix >= args.prompt:
         ap.error(f"--shared-prefix ({args.shared_prefix}) must be smaller "
                  f"than --prompt ({args.prompt}): the shared system prompt "
                  "is part of the per-request prompt budget")
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if not args.full:
         cfg = cfg.reduced()
-    mesh = make_test_mesh(1, 1)
-    controller = None
-    if cfg.is_moe:
-        spec = M.EPSpec.build(mesh, cfg, ep_axes=("model",),
-                              slots=cfg.num_experts, capacity=8192,
-                              slot_capacity=16384)
-        rt = tr.Runtime(cfg=cfg, mesh=mesh, moe_impl="ep", ep_spec=spec)
-        pl = M.uniform_placement(spec.n_ep, spec.slots, cfg.num_experts)
-        _, n_groups = cfg.layer_pattern()
-        pls = tr.stack_placement(pl, n_groups)
-        cm = CostModel(expert_bytes=3 * cfg.d_model * cfg.d_ff * 2,
-                       activation_bytes=cfg.d_model * 2, bandwidth=62.5e6,
-                       tokens_per_horizon=1e5)
-        controller = PlacementController(
-            policy=get_policy(args.policy), cost=cm,
-            cluster=ClusterView.from_ep_spec(spec, n_groups),
-            interval=args.review_rounds)
-        # dense master copy: live migration re-gathers expert slots from it
-        rt_dense = tr.Runtime(cfg=cfg, mesh=mesh, moe_impl="dense")
-        params_dense = tr.init_params(rt_dense, jax.random.PRNGKey(0))
-        params = dict(params_dense)
-        params["groups"] = M.regather_ep_groups(params_dense["groups"], pls,
-                                                n_groups)
-        dense_master = params_dense["groups"]
-    else:
-        rt = tr.Runtime(cfg=cfg, mesh=mesh)
-        pls = None
-        params = tr.init_params(rt, jax.random.PRNGKey(0))
-        dense_master = None
-    engine = ServingEngine(rt=rt, params=params, placement=pls,
-                           dense_master=dense_master,
-                           max_len=args.prompt + args.steps + 8)
     if args.warmup and args.dense_pool:
         ap.error("--warmup needs the paged pool (drop --dense-pool)")
     tracer = Tracer(clock="ticks") if args.trace_out else None
-    if controller is not None and tracer is not None:
-        controller.tracer = tracer          # PLACEMENT_REVIEW decisions
-    runtime = ServingRuntime(engine, max_slots=args.slots,
-                             controller=controller,
-                             paged=False if args.dense_pool else None,
-                             block_size=args.block_size,
-                             n_blocks=args.blocks,
-                             prefix_cache=args.prefix_cache,
-                             warmup=args.warmup,
-                             warmup_origins="untagged",
-                             tracer=tracer)
+    engine, controller, runtime = build_server(
+        cfg, max_len=args.prompt + args.steps + 8, slots=args.slots,
+        block_size=args.block_size, blocks=args.blocks,
+        dense_pool=args.dense_pool, prefix_cache=args.prefix_cache,
+        warmup=args.warmup, policy=args.policy,
+        review_rounds=args.review_rounds,
+        dtype=jnp.bfloat16 if args.full else jnp.float32, tracer=tracer)
+    if engine.rt.ep_spec is not None:
+        spec = engine.rt.ep_spec
+        print(f"EP capacities: C={spec.capacity} C2={spec.slot_capacity} "
+              f"(slots={spec.slots} per rank)")
     if args.warmup:
         print(f"warmup: {runtime.executables_compiled} executables in "
               f"{runtime.warmup_seconds:.1f}s")

@@ -39,18 +39,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import dense_init, rms_norm
 
-if hasattr(jax, "shard_map"):             # jax >= 0.5
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:                                     # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _esm
-
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        return _esm(f, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, check_rep=False)
-
-
 @dataclasses.dataclass(frozen=True)
 class EPSpec:
     """Static expert-parallel geometry."""
@@ -175,19 +163,34 @@ def dense_to_ep(dense_p: dict, placement: EPPlacement) -> dict:
     return out
 
 
+def _gather_slots(w, s2e):
+    """w: stacked expert weights [G, E, ...]; s2e: stacked slot tables
+    [G, n_ep, S] -> [G, n_ep, S, ...] (empty slots hold expert 0)."""
+    g = jnp.arange(w.shape[0])[:, None, None]
+    return w[g, jnp.maximum(s2e, 0)]
+
+
+def gather_expert_slots(w, slot_to_expert, sharding=None):
+    """One stacked expert weight of the dense master ([G, E, ...]) gathered
+    into EP slot layout ([G, n_ep, S, ...]) by the stacked placement table,
+    in one program for all layers. ``sharding``: where the result lives
+    (e.g. slots sharded over the EP axis); None leaves it to the inputs."""
+    return jax.jit(_gather_slots, out_shardings=sharding)(w, slot_to_expert)
+
+
 def regather_ep_groups(dense_groups: dict, placement_stacked,
-                       n_groups: int) -> dict:
-    """Apply ``dense_to_ep`` per layer group: dense master group params
-    (stacked [G, E, ...]) + stacked placement tables -> EP-layout groups.
-    Non-MoE groups pass through unchanged."""
+                       n_groups: int, sharding=None) -> dict:
+    """``dense_to_ep`` for every layer group at once: dense master group
+    params (stacked [G, E, ...]) + stacked placement tables -> EP-layout
+    groups. Non-MoE groups pass through unchanged; ``sharding`` places the
+    gathered expert slots (see ``gather_expert_slots``)."""
+    s2e = placement_stacked.slot_to_expert
+    assert s2e.shape[0] == n_groups, (s2e.shape, n_groups)
     out = {}
     for k, v in dense_groups.items():
         if "router" in v:
-            per = [dense_to_ep(jax.tree.map(lambda a: a[g], v),
-                               jax.tree.map(lambda a: a[g],
-                                            placement_stacked))
-                   for g in range(n_groups)]
-            out[k] = jax.tree.map(lambda *xs: jnp.stack(xs), *per)
+            out[k] = {**v, **{w: gather_expert_slots(v[w], s2e, sharding)
+                              for w in ("w1", "w3", "w2")}}
         else:
             out[k] = v
     return out
@@ -470,9 +473,10 @@ def moe_apply_ep(p, cfg, x, *, mesh, spec: EPSpec, placement: EPPlacement,
                                             pl_loc, cfg, spec, use_kernel)
             return o.reshape(b_, t_, d_), c, l, a
 
-        fn = _shard_map(body3, mesh=mesh,
-                        in_specs=(rows_spec3, wspec, pl_spec),
-                        out_specs=(rows_spec3, P(), P(), P()))
+        fn = jax.shard_map(body3, mesh=mesh,
+                           in_specs=(rows_spec3, wspec, pl_spec),
+                           out_specs=(rows_spec3, P(), P(), P()),
+                           check_vma=False)
         out, counts, local, aux = fn(h, p_in, placement)
         stats = {"counts": counts.sum(0), "counts_per_rank": counts,
                  "aux_loss": aux, "local_frac": local}
@@ -523,10 +527,10 @@ def moe_apply_ep(p, cfg, x, *, mesh, spec: EPSpec, placement: EPPlacement,
                         else jnp.ones((B, T)), jnp.float32)
     origin_rows = to_rows(origin if use_origin
                           else jnp.zeros((B, T), jnp.int32), jnp.int32)
-    fn = _shard_map(body, mesh=mesh,
-                    in_specs=(rows_spec, mask_spec, mask_spec, wspec,
-                              pl_spec),
-                    out_specs=out_specs)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(rows_spec, mask_spec, mask_spec, wspec,
+                                 pl_spec),
+                       out_specs=out_specs, check_vma=False)
     out_rows, counts, local, aux = fn(rows, mask_rows, origin_rows, p_in,
                                       placement)
     out = out_rows[:B * T].reshape(B, T, D)

@@ -384,18 +384,26 @@ class ServingEngine:
         master weights (if available) and swap the tables. No recompile —
         placement tables and weights are both jit arguments, and the AOT
         executables stay valid because the re-gathered arrays keep their
-        shapes and dtypes."""
+        shapes, dtypes and shardings. One weight is swapped at a time, each
+        into a fresh params dict, so the old copy of a weight can be freed
+        before the next is gathered: at full width a second EP copy of
+        every expert would not fit beside the first and the master."""
         self.placement = jax.tree.map(jnp.asarray, new_placement_stacked)
         if self.dense_master is None:
             return
-        regathered = moe_mod.regather_ep_groups(
-            self.dense_master, self.placement, self.n_groups
-        )
-        moe_groups = {
-            k: v
-            for k, v in regathered.items()
-            if "router" in self.dense_master[k]
-        }
-        params = dict(self.params)
-        params["groups"] = {**self.params["groups"], **moe_groups}
-        self.params = params
+        s2e = self.placement.slot_to_expert
+        for k, master in self.dense_master.items():
+            if "router" not in master:
+                continue
+            for w in ("w1", "w3", "w2"):
+                old = self.params["groups"][k][w]
+                sharding = (
+                    old.sharding
+                    if isinstance(old.sharding, jax.sharding.NamedSharding)
+                    else None
+                )
+                del old
+                new = moe_mod.gather_expert_slots(master[w], s2e, sharding)
+                groups = dict(self.params["groups"])
+                groups[k] = {**groups[k], w: new}
+                self.params = {**self.params, "groups": groups}

@@ -7,7 +7,7 @@ from repro.configs import get_config
 from repro.models import moe as M
 
 cfg = get_config("mixtral-8x7b").reduced()
-from repro.launch.mesh import make_test_mesh, set_mesh
+from repro.launch.mesh import make_test_mesh
 mesh = make_test_mesh(2, 4)
 key = jax.random.PRNGKey(0)
 dense_p = M.moe_params_dense(key, cfg)
@@ -23,7 +23,7 @@ for ep_axes in [("model",), ("data", "model")]:
         ("uniform", M.uniform_placement(n_ep, spec.slots, cfg.num_experts)),
     ]:
         ep_p = M.dense_to_ep(dense_p, pl)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             for mode in ["prefill", "decode"]:
                 xx = x if mode != "decode" else x[:, :1]
                 rr = ref if mode != "decode" else \

@@ -7,7 +7,7 @@ from repro.configs import get_config
 from repro.models import moe as M
 from repro.models import transformer as tr
 
-from repro.launch.mesh import make_test_mesh, set_mesh
+from repro.launch.mesh import make_test_mesh
 mesh = make_test_mesh(2, 4)
 key = jax.random.PRNGKey(0)
 
@@ -21,7 +21,7 @@ ref_loss = ref_lg = None
 for layout in ("tp", "sp", "cp", "fsdp"):
     rt = tr.Runtime(cfg=cfg, mesh=mesh, layout=layout,
                     remat_policy="dots+kv" if layout != "tp" else "none")
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         loss, _ = jax.jit(lambda p, t: tr.loss_fn(rt, p, t,
                                                   jnp.roll(t, -1, 1)))(params, toks)
         lg, _, _ = jax.jit(lambda p, t: tr.prefill(rt, p, tokens=t))(params, toks)
@@ -50,7 +50,7 @@ for k, v in params_d["groups"].items():
         ge[k] = jax.tree.map(lambda *xs: jnp.stack(xs), *per)
 params_e = dict(params_d)
 params_e["groups"] = ge
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     lg_ref, _, _ = jax.jit(lambda p, t: tr.prefill(rt_d, p, tokens=t))(params_d, toks)
     for layout in ("tp", "sp", "fsdp"):
         rt = tr.Runtime(cfg=cfg, mesh=mesh, moe_impl="ep", ep_spec=spec,

@@ -11,7 +11,7 @@ from repro.models import moe as M
 from repro.models import transformer as tr
 
 cfg = get_config("mixtral-8x7b").reduced()   # 4 experts, top-2
-from repro.launch.mesh import make_test_mesh, set_mesh
+from repro.launch.mesh import make_test_mesh
 mesh = make_test_mesh(2, 4)
 spec = M.EPSpec.build(mesh, cfg, ep_axes=("model",), slots=2,
                       capacity=512, slot_capacity=2048)
@@ -40,7 +40,7 @@ toks = jax.random.randint(key, (B, T), 0, cfg.vocab_size)
 
 pl_u = M.uniform_placement(spec.n_ep, spec.slots, cfg.num_experts)
 pls_u = tr.stack_placement(pl_u, n_groups)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     _, _, st = jax.jit(lambda p, t, q: tr.prefill(
         rt, p, tokens=t, placement=q))(regather(pls_u), toks, pls_u)
 counts = np.asarray(st["counts_per_rank"], np.float64)   # [G, n_ep, E]
@@ -50,7 +50,7 @@ freqs = counts / np.maximum(counts.sum(-1, keepdims=True), 1e-9)
 plan = dancemoe_placement(freqs, np.full(spec.n_ep, spec.slots * n_groups),
                           np.full(spec.n_ep, spec.slots))
 pls_d = build_ep_placement(plan, spec.slots)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     lg_d, _, st2 = jax.jit(lambda p, t, q: tr.prefill(
         rt, p, tokens=t, placement=q))(regather(pls_d), toks, pls_d)
 lf_dance = float(st2["local_frac"].mean())

@@ -153,6 +153,7 @@ def run_once(built=None):
 
 
 def main():
+    print(f"platform: {jax.devices()[0].platform} x{len(jax.devices())}")
     built = build_engine()
     cfg, _, n_groups, engine = built
 

@@ -10,7 +10,7 @@ from repro.models import moe as M
 from repro.models import transformer as tr
 
 cfg = get_config("mixtral-8x7b").reduced()
-from repro.launch.mesh import make_test_mesh, set_mesh
+from repro.launch.mesh import make_test_mesh
 mesh = make_test_mesh(2, 4)
 spec = M.EPSpec.build(mesh, cfg, ep_axes=("model",), slots=2,
                       capacity=512, slot_capacity=2048)
@@ -33,7 +33,7 @@ params_e["groups"] = ge
 B, T = 4, 16
 toks = jax.random.randint(key, (B, T), 0, cfg.vocab_size)
 
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     (_, md) = jax.jit(lambda p, t: tr.loss_fn(rt_d, p, t,
                                               jnp.roll(t, -1, 1)))(params_d,
                                                                    toks)

@@ -72,6 +72,7 @@ def build_engine():
 
 
 def main():
+    print(f"platform: {jax.devices()[0].platform} x{len(jax.devices())}")
     engine = build_engine()
     cluster = EdgeCluster(
         "runtime", engine=engine, n_servers=N_SERVERS, slo_aware=True,

@@ -94,3 +94,12 @@ def test_head_padding_function_preserving(name):
     hp = cfg.padded_heads(16)
     assert hp % 16 == 0 and hp >= cfg.num_heads
     assert hp % cfg.num_kv_heads == 0
+
+
+def test_peak_table_is_keyed_by_device_kind():
+    from repro.launch.mesh import peaks
+
+    v5e = peaks("TPU v5 lite")
+    assert v5e["bf16_flops"] == 197e12 and v5e["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(KeyError):
+        peaks("cpu")

@@ -7,7 +7,7 @@ from pathlib import Path
 
 import pytest
 
-from repro.launch.dryrun import ASSIGNED_ARCHS
+from repro.configs import ASSIGNED_ARCHS
 
 RESULTS = Path(__file__).parent.parent / "results" / "dryrun"
 SHAPES = ["train_4k", "prefill_32k", "decode_32k", "long_500k"]

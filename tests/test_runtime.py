@@ -412,3 +412,32 @@ def test_local_frac_warm_vs_sync_subprocess():
     assert r.returncode == 0, \
         f"local_frac_warm_sync.py failed:\n{r.stdout}\n{r.stderr}"
     assert "ALL OK" in r.stdout
+
+
+@pytest.mark.parametrize("dense_pool", [False, True])
+def test_build_server_is_dropless_and_token_identical(dense_pool):
+    """The serving CLI's construction path (``chip_smoke.py`` drives the
+    same one): EP capacities sized so no token can drop (the dense pool
+    prefills up to ``slots`` prompts in one step), the DanceMoE controller
+    applying a plan mid-stream, the warmed zero-stall loop on the paged
+    pool — token-identical to sequential per-request generate()."""
+    from repro.launch.serve import build_server, ep_capacities
+
+    cfg = get_config("mixtral-8x7b").reduced()
+    engine, _, rtm = build_server(cfg, max_len=40, slots=2, block_size=8,
+                                  dense_pool=dense_pool,
+                                  warmup=not dense_pool, review_rounds=4)
+    spec = engine.rt.ep_spec
+    rows = 2 * 40 if dense_pool else 40
+    assert (spec.capacity, spec.slot_capacity) == \
+        ep_capacities(rows, cfg.top_k, 1) == (rows * cfg.top_k, rows)
+    src = TaskTokenSource("build-server", cfg.vocab_size, seed=1)
+    prompts = [src.sample(1, 16)[0] for _ in range(3)]
+    handles = [rtm.enqueue(Request(prompt=p, max_new_tokens=8))
+               for p in prompts]
+    rtm.run()
+    assert rtm.migrations
+    assert rtm.traces_after_warmup == 0 or dense_pool
+    for p, h in zip(prompts, handles):
+        ref, _ = engine.generate(p[None], steps=8)
+        np.testing.assert_array_equal(h.result(), ref[0])

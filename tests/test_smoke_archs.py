@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
-from repro.launch.dryrun import ASSIGNED_ARCHS
+from repro.configs import ASSIGNED_ARCHS
 from repro.models import transformer as tr
 from repro.optim.adamw import adamw
 from repro.training.train_loop import make_train_step

@@ -444,3 +444,20 @@ def test_runtime_goodput_subprocess():
     assert r.returncode == 0, (
         f"workload_runtime.py failed:\n{r.stdout}\n{r.stderr}")
     assert "ALL OK" in r.stdout
+
+
+def test_token_streams_replay_across_processes():
+    """Prompts and task profiles are seeded by a stable hash, not the
+    per-interpreter salted ``hash()``: two processes with different
+    ``PYTHONHASHSEED`` draw the same streams."""
+    code = ("from repro.data.pipeline import TaskTokenSource; "
+            "from repro.data.traces import make_task_profile; "
+            "print(TaskTokenSource('t', 1000, seed=3).sample(2, 8).tolist(), "
+            "make_task_profile('arith', 2, 4, seed=1).probs.round(12).tolist())")
+    src = str(Path(__file__).resolve().parent.parent / "src")
+    outs = [subprocess.run([sys.executable, "-c", code], capture_output=True,
+                           text=True, check=True, timeout=120,
+                           env={**os.environ, "PYTHONPATH": src,
+                                "PYTHONHASHSEED": salt}).stdout
+            for salt in ("1", "2")]
+    assert outs[0] == outs[1] and outs[0].strip()
