@@ -9,7 +9,7 @@ runner)."""
 
 from __future__ import annotations
 
-SCHEMA_NAME = "bench-serving/v8"
+SCHEMA_NAME = "bench-serving/v9"
 
 # metric key -> ("scalar" | "pair" | "stats") shape requirement.
 # v2 extended v1 (same keys, same shapes) with the EdgeCluster section;
@@ -20,8 +20,8 @@ SCHEMA_NAME = "bench-serving/v8"
 # (``metrics.tiers``); v7 adds the streaming-workload / SLO-scheduling
 # section (``metrics.workload``); v8 adds the unified-observability
 # section (``metrics.obs``) and the exported-trace artifact contract
-# (``validate_trace_doc``) — extend, don't fork, when adding serving
-# metrics.
+# (``validate_trace_doc``); v9 adds ``metrics.perf.ep_rows_dropped`` —
+# extend, don't fork, when adding serving metrics.
 # Field-by-field documentation: docs/benchmarks.md.
 _REQUIRED_METRICS = {
     "admitted_concurrency": "pair",  # {"cache": n, "nocache": n}
@@ -73,6 +73,7 @@ _REQUIRED_PERF = {
     "rounds_timed": "scalar",  # decode rounds behind the percentiles
     "decode_round_ms": "p50p99",  # per-round wall time, warmed loop
     "ttft_ms": "p50p99",  # wall-clock time to first token
+    "ep_rows_dropped": "scalar",  # EP capacity drops (v9; dropless: 0)
 }
 
 

@@ -265,6 +265,13 @@ def _bucket(keys, n_buckets, capacity):
     return order, pos, keep
 
 
+def _dropped(keys, n_buckets, keep):
+    """Entries with a bucket (``keys < n_buckets``) that ``_bucket`` left
+    out for want of capacity."""
+    return (jnp.sum(keys < n_buckets, dtype=jnp.int32)
+            - jnp.sum(keep, dtype=jnp.int32))
+
+
 def _local_slots(p):
     """Per-device slice of the EP weights inside shard_map ([1,S,...]→[S,...])."""
     return {k: p[k][0] for k in ("w1", "w3", "w2")}
@@ -283,48 +290,52 @@ def _ep_dispatch_local(h_loc, p, placement, cfg, spec: EPSpec,
     E, K = cfg.num_experts, cfg.top_k
     n_ep, S, C, C2 = spec.n_ep, spec.slots, spec.capacity, spec.slot_capacity
     my = lax.axis_index(spec.axes)
-    probs, topv, topi = route(p["router"], h_loc, K)
+    with jax.named_scope("moe.route"):
+        probs, topv, topi = route(p["router"], h_loc, K)
 
-    flat_e = topi.reshape(R * K)
-    flat_w = topv.reshape(R * K)
-    flat_src = jnp.repeat(jnp.arange(R), K)
-    tgt = placement.expert_to_target[my, flat_e]              # [RK]
-    order, pos, keep = _bucket(tgt, n_ep, C)
-    dest = jnp.where(keep, tgt[order] * C + pos, n_ep * C)    # OOB = drop
-    buf_x = jnp.zeros((n_ep * C, D), h_loc.dtype).at[dest].set(
-        h_loc[flat_src[order]], mode="drop")
-    buf_e = jnp.full((n_ep * C,), -1, jnp.int32).at[dest].set(
-        flat_e[order].astype(jnp.int32), mode="drop")
+    with jax.named_scope("moe.dispatch"):
+        flat_e = topi.reshape(R * K)
+        flat_w = topv.reshape(R * K)
+        flat_src = jnp.repeat(jnp.arange(R), K)
+        tgt = placement.expert_to_target[my, flat_e]              # [RK]
+        order, pos, keep = _bucket(tgt, n_ep, C)
+        dest = jnp.where(keep, tgt[order] * C + pos, n_ep * C)    # OOB = drop
+        buf_x = jnp.zeros((n_ep * C, D), h_loc.dtype).at[dest].set(
+            h_loc[flat_src[order]], mode="drop")
+        buf_e = jnp.full((n_ep * C,), -1, jnp.int32).at[dest].set(
+            flat_e[order].astype(jnp.int32), mode="drop")
 
-    recv_x = lax.all_to_all(buf_x.reshape(n_ep, C, D), spec.axes, 0, 0,
-                            tiled=False)
-    recv_e = lax.all_to_all(buf_e.reshape(n_ep, C), spec.axes, 0, 0,
-                            tiled=False)
+        recv_x = lax.all_to_all(buf_x.reshape(n_ep, C, D), spec.axes, 0, 0,
+                                tiled=False)
+        recv_e = lax.all_to_all(buf_e.reshape(n_ep, C), spec.axes, 0, 0,
+                                tiled=False)
 
-    # --- receiver: slot bucketing + grouped FFN over the slot buffer ---
-    xs = recv_x.reshape(n_ep * C, D)
-    es = recv_e.reshape(n_ep * C)
-    slot = jnp.where(es >= 0,
-                     placement.expert_to_slot[my, jnp.maximum(es, 0)], -1)
-    slot_key = jnp.where(slot >= 0, slot, S).astype(jnp.int32)
-    order2, pos2, keep2 = _bucket(slot_key, S, C2)
-    dest2 = jnp.where(keep2, slot_key[order2] * C2 + pos2, S * C2)
-    sbuf = jnp.zeros((S * C2, D), h_loc.dtype).at[dest2].set(
-        xs[order2], mode="drop")
+        # --- receiver: slot bucketing into the slot buffer ---
+        xs = recv_x.reshape(n_ep * C, D)
+        es = recv_e.reshape(n_ep * C)
+        slot = jnp.where(es >= 0,
+                         placement.expert_to_slot[my, jnp.maximum(es, 0)], -1)
+        slot_key = jnp.where(slot >= 0, slot, S).astype(jnp.int32)
+        order2, pos2, keep2 = _bucket(slot_key, S, C2)
+        dest2 = jnp.where(keep2, slot_key[order2] * C2 + pos2, S * C2)
+        sbuf = jnp.zeros((S * C2, D), h_loc.dtype).at[dest2].set(
+            xs[order2], mode="drop")
     w = _local_slots(p)
-    y = grouped_ffn(sbuf.reshape(S, C2, D), w["w1"], w["w3"], w["w2"],
-                    use_kernel).reshape(S * C2, D)
-    # scatter expert outputs back into recv-buffer order
-    got = jnp.where(keep2[:, None],
-                    y[jnp.clip(dest2, 0, S * C2 - 1)], 0).astype(h_loc.dtype)
-    out_tok = jnp.zeros((n_ep * C, D), h_loc.dtype).at[order2].set(got)
+    with jax.named_scope("moe.expert_ffn"):
+        y = grouped_ffn(sbuf.reshape(S, C2, D), w["w1"], w["w3"], w["w2"],
+                        use_kernel).reshape(S * C2, D)
+    with jax.named_scope("moe.combine"):
+        # scatter expert outputs back into recv-buffer order
+        got = jnp.where(keep2[:, None], y[jnp.clip(dest2, 0, S * C2 - 1)],
+                        0).astype(h_loc.dtype)
+        out_tok = jnp.zeros((n_ep * C, D), h_loc.dtype).at[order2].set(got)
 
-    back = lax.all_to_all(out_tok.reshape(n_ep, C, D), spec.axes, 0, 0,
-                          tiled=False).reshape(n_ep * C, D)
-    contrib = jnp.where(keep[:, None],
-                        back[jnp.clip(dest, 0, n_ep * C - 1)], 0)
-    contrib = contrib * flat_w[order][:, None].astype(h_loc.dtype)
-    out = jnp.zeros((R, D), h_loc.dtype).at[flat_src[order]].add(contrib)
+        back = lax.all_to_all(out_tok.reshape(n_ep, C, D), spec.axes, 0, 0,
+                              tiled=False).reshape(n_ep * C, D)
+        contrib = jnp.where(keep[:, None],
+                            back[jnp.clip(dest, 0, n_ep * C - 1)], 0)
+        contrib = contrib * flat_w[order][:, None].astype(h_loc.dtype)
+        out = jnp.zeros((R, D), h_loc.dtype).at[flat_src[order]].add(contrib)
 
     # --- stats: f_n(e) per *originating* server. Every row scatter-adds
     # its expert choices into its origin's row of an [n_ep, E] matrix; the
@@ -342,7 +353,11 @@ def _ep_dispatch_local(h_loc, p, placement, cfg, spec: EPSpec,
     local = lax.pmean(jnp.mean((tgt == my).astype(jnp.float32)),
                       spec.mesh_axes)
     aux = lax.pmean(aux_load_balance_loss(probs, topi, E), spec.mesh_axes)
-    return out, counts, local, aux
+    # (row, expert) pairs dropped at either capacity: C on the send side,
+    # C2 on the receive side (padding rows included)
+    dropped = lax.psum(_dropped(tgt, n_ep, keep)
+                       + _dropped(slot_key, S, keep2), spec.mesh_axes)
+    return out, counts, local, aux, dropped
 
 
 def _ep_gather_local(h_loc, m_loc, p, placement, cfg, spec: EPSpec,
@@ -359,45 +374,51 @@ def _ep_gather_local(h_loc, m_loc, p, placement, cfg, spec: EPSpec,
     E, K = cfg.num_experts, cfg.top_k
     n_ep, S, C2 = spec.n_ep, spec.slots, spec.slot_capacity
     my = lax.axis_index(spec.axes)
-    h_all = (lax.all_gather(h_loc, gather_axes, tiled=True)
-             if gather_axes else h_loc)                        # [Btok, D]
-    m_all = (lax.all_gather(m_loc, gather_axes, tiled=True)
-             if gather_axes else m_loc)                        # [Btok]
+    with jax.named_scope("moe.dispatch"):
+        h_all = (lax.all_gather(h_loc, gather_axes, tiled=True)
+                 if gather_axes else h_loc)                    # [Btok, D]
+        m_all = (lax.all_gather(m_loc, gather_axes, tiled=True)
+                 if gather_axes else m_loc)                    # [Btok]
     Btok = h_all.shape[0]
-    probs, topv, topi = route(p["router"], h_all, K)
-    if o_loc is not None:
-        # explicit origin: the edge server each request arrived at
-        src_ep = (lax.all_gather(o_loc, gather_axes, tiled=True)
-                  if gather_axes else o_loc)                   # [Btok]
-    else:
-        # positional fallback: requests "arrive at" the first EP rank of
-        # their batch shard (the paper's server identity)
-        n_gather = max(Btok // R, 1)
-        span = max(n_ep // n_gather, 1)
-        src_ep = (jnp.arange(Btok) // R) * span                # [Btok]
-    flat_e = topi.reshape(-1)
-    flat_src = jnp.repeat(jnp.arange(Btok), K)
-    tgt = placement.expert_to_target[src_ep[flat_src], flat_e]
-    mine = tgt == my
-    slot = jnp.where(mine, placement.expert_to_slot[my, flat_e], -1)
-    slot_key = jnp.where(slot >= 0, slot, S).astype(jnp.int32)
-    order2, pos2, keep2 = _bucket(slot_key, S, C2)
-    dest2 = jnp.where(keep2, slot_key[order2] * C2 + pos2, S * C2)
-    sbuf = jnp.zeros((S * C2, D), h_loc.dtype).at[dest2].set(
-        h_all[flat_src[order2]], mode="drop")
+    with jax.named_scope("moe.route"):
+        probs, topv, topi = route(p["router"], h_all, K)
+    with jax.named_scope("moe.dispatch"):
+        if o_loc is not None:
+            # explicit origin: the edge server each request arrived at
+            src_ep = (lax.all_gather(o_loc, gather_axes, tiled=True)
+                      if gather_axes else o_loc)               # [Btok]
+        else:
+            # positional fallback: requests "arrive at" the first EP rank
+            # of their batch shard (the paper's server identity)
+            n_gather = max(Btok // R, 1)
+            span = max(n_ep // n_gather, 1)
+            src_ep = (jnp.arange(Btok) // R) * span            # [Btok]
+        flat_e = topi.reshape(-1)
+        flat_src = jnp.repeat(jnp.arange(Btok), K)
+        tgt = placement.expert_to_target[src_ep[flat_src], flat_e]
+        mine = tgt == my
+        slot = jnp.where(mine, placement.expert_to_slot[my, flat_e], -1)
+        slot_key = jnp.where(slot >= 0, slot, S).astype(jnp.int32)
+        order2, pos2, keep2 = _bucket(slot_key, S, C2)
+        dest2 = jnp.where(keep2, slot_key[order2] * C2 + pos2, S * C2)
+        sbuf = jnp.zeros((S * C2, D), h_loc.dtype).at[dest2].set(
+            h_all[flat_src[order2]], mode="drop")
     w = _local_slots(p)
-    y = grouped_ffn(sbuf.reshape(S, C2, D), w["w1"], w["w3"], w["w2"],
-                    use_kernel).reshape(S * C2, D)
-    yw = jnp.where(keep2[:, None],
-                   y[jnp.clip(dest2, 0, S * C2 - 1)], 0).astype(h_loc.dtype)
-    yw = yw * topv.reshape(-1)[order2][:, None].astype(h_loc.dtype)
-    out_all = jnp.zeros((Btok, D), h_loc.dtype).at[flat_src[order2]].add(yw)
-    out_all = lax.psum(out_all, spec.axes)
-    if gather_axes:
-        g_idx = lax.axis_index(gather_axes)
-        out = lax.dynamic_slice_in_dim(out_all, g_idx * R, R, 0)
-    else:
-        out = out_all
+    with jax.named_scope("moe.expert_ffn"):
+        y = grouped_ffn(sbuf.reshape(S, C2, D), w["w1"], w["w3"], w["w2"],
+                        use_kernel).reshape(S * C2, D)
+    with jax.named_scope("moe.combine"):
+        yw = jnp.where(keep2[:, None], y[jnp.clip(dest2, 0, S * C2 - 1)],
+                       0).astype(h_loc.dtype)
+        yw = yw * topv.reshape(-1)[order2][:, None].astype(h_loc.dtype)
+        out_all = jnp.zeros((Btok, D), h_loc.dtype).at[
+            flat_src[order2]].add(yw)
+        out_all = lax.psum(out_all, spec.axes)
+        if gather_axes:
+            g_idx = lax.axis_index(gather_axes)
+            out = lax.dynamic_slice_in_dim(out_all, g_idx * R, R, 0)
+        else:
+            out = out_all
 
     # stats: every EP rank sees the same gathered tokens, so the per-origin
     # [n_ep, E] matrix is computed identically everywhere (replicated over
@@ -409,13 +430,17 @@ def _ep_gather_local(h_loc, m_loc, p, placement, cfg, spec: EPSpec,
         mode="drop")
     non_ep = tuple(a for a in spec.mesh_axes
                    if a not in spec.axes and a not in gather_axes)
+    # each EP rank buckets only the pairs bound for it: their C2 drops
+    # add up over the EP axes (padding rows included)
+    dropped = lax.psum(_dropped(slot_key, S, keep2), spec.axes)
     if non_ep:
         counts = lax.psum(counts, non_ep)
+        dropped = lax.psum(dropped, non_ep)
     local = lax.pmean(
         jnp.sum((tgt == src_ep[flat_src]).astype(jnp.float32) * valid)
         / jnp.maximum(jnp.sum(valid), 1.0), spec.mesh_axes)
     aux = lax.pmean(aux_load_balance_loss(probs, topi, E), spec.mesh_axes)
-    return out, counts, local, aux
+    return out, counts, local, aux, dropped
 
 
 def moe_apply_ep(p, cfg, x, *, mesh, spec: EPSpec, placement: EPPlacement,
@@ -469,17 +494,18 @@ def moe_apply_ep(p, cfg, x, *, mesh, spec: EPSpec, placement: EPPlacement,
 
         def body3(h3, p_loc, pl_loc):
             b_, t_, d_ = h3.shape
-            o, c, l, a = _ep_dispatch_local(h3.reshape(b_ * t_, d_), p_loc,
-                                            pl_loc, cfg, spec, use_kernel)
-            return o.reshape(b_, t_, d_), c, l, a
+            o, c, l, a, dr = _ep_dispatch_local(
+                h3.reshape(b_ * t_, d_), p_loc, pl_loc, cfg, spec,
+                use_kernel)
+            return o.reshape(b_, t_, d_), c, l, a, dr
 
         fn = jax.shard_map(body3, mesh=mesh,
                            in_specs=(rows_spec3, wspec, pl_spec),
-                           out_specs=(rows_spec3, P(), P(), P()),
+                           out_specs=(rows_spec3, P(), P(), P(), P()),
                            check_vma=False)
-        out, counts, local, aux = fn(h, p_in, placement)
+        out, counts, local, aux, dropped = fn(h, p_in, placement)
         stats = {"counts": counts.sum(0), "counts_per_rank": counts,
-                 "aux_loss": aux, "local_frac": local}
+                 "aux_loss": aux, "local_frac": local, "dropped": dropped}
         return x + out.astype(x.dtype), stats
     else:
         rows_spec = P(spec.dispatch_row_axes, None)
@@ -491,7 +517,7 @@ def moe_apply_ep(p, cfg, x, *, mesh, spec: EPSpec, placement: EPPlacement,
                                       o_loc=o_loc if use_origin else None)
 
     # counts leave both bodies as a replicated [n_ep, E] per-origin matrix
-    out_specs = (rows_spec, P(), P(), P())
+    out_specs = (rows_spec, P(), P(), P(), P())
     mask_spec = P(rows_spec[0])
     # the row axis must divide evenly over its mesh axes; short batches
     # (e.g. a chunked-prefill geometry whose max_slots * block_size is not
@@ -531,12 +557,12 @@ def moe_apply_ep(p, cfg, x, *, mesh, spec: EPSpec, placement: EPPlacement,
                        in_specs=(rows_spec, mask_spec, mask_spec, wspec,
                                  pl_spec),
                        out_specs=out_specs, check_vma=False)
-    out_rows, counts, local, aux = fn(rows, mask_rows, origin_rows, p_in,
-                                      placement)
+    out_rows, counts, local, aux, dropped = fn(rows, mask_rows, origin_rows,
+                                               p_in, placement)
     out = out_rows[:B * T].reshape(B, T, D)
     if batch_row_axes and B % n_batch == 0:
         out = lax.with_sharding_constraint(
             out, NamedSharding(mesh, P(batch_row_axes, None, None)))
     stats = {"counts": counts.sum(0), "counts_per_rank": counts,
-             "aux_loss": aux, "local_frac": local}
+             "aux_loss": aux, "local_frac": local, "dropped": dropped}
     return x + out.astype(x.dtype), stats

@@ -139,14 +139,15 @@ def _apply_block(rt: Runtime, kind: str, p, h, *, mode, cache, pos,
     sp = _sp_active(rt, mode)
     if kind in (ATTN, SHARED_ATTN):
         h_in = _sp_gather(rt, h) if sp else h
-        out, c = attn.attn_apply(
-            p, cfg, h_in, ep=rt.ep, mode=mode, cache=cache, pos=pos,
-            window=window, norm_eps=cfg.norm_eps,
-            use_kernel=rt.use_kernel and mode not in ("decode", "chunk"),
-            mesh=rt.mesh,
-            cache_seq_sharded=rt.cache_seq_sharded, residual=not sp,
-            gather_kv=rt.layout in ("cp", "fsdp"), paged=paged,
-            quant_consistent=rt.kv_quant_consistent)
+        with jax.named_scope("attn"):
+            out, c = attn.attn_apply(
+                p, cfg, h_in, ep=rt.ep, mode=mode, cache=cache, pos=pos,
+                window=window, norm_eps=cfg.norm_eps,
+                use_kernel=rt.use_kernel and mode not in ("decode", "chunk"),
+                mesh=rt.mesh,
+                cache_seq_sharded=rt.cache_seq_sharded, residual=not sp,
+                gather_kv=rt.layout in ("cp", "fsdp"), paged=paged,
+                quant_consistent=rt.kv_quant_consistent)
         if sp:
             out = h + _sp_scatter(rt, out)          # reduce-scatter the delta
         return out, c
@@ -302,9 +303,10 @@ def _embed(rt: Runtime, params, tokens):
 
 
 def _logits(rt: Runtime, params, h):
-    h = rms_norm(h, params["final_norm"], rt.cfg.norm_eps)
-    w = params["embed"].T if rt.cfg.tie_embeddings else params["lm_head"]
-    return h @ w
+    with jax.named_scope("head"):
+        h = rms_norm(h, params["final_norm"], rt.cfg.norm_eps)
+        w = params["embed"].T if rt.cfg.tie_embeddings else params["lm_head"]
+        return h @ w
 
 
 def _chunked_xent(rt: Runtime, params, h, targets):

@@ -186,6 +186,12 @@ class RequestHandle:
     Backends append :class:`Event` records via :meth:`_emit`; consumers read
     ``events``, ``tokens`` (runtime backend), ``done`` and ``metrics`` (the
     FINISHED payload), or call :meth:`result` for the generated tokens.
+
+    ``wall`` holds the runtime backend's host-clock stamps
+    (``time.perf_counter()`` seconds) of the lifecycle: ``enqueued``,
+    ``admitted`` (slot granted), ``first_token`` (drained) and
+    ``finished``. Events stay on the backend's model clock; the stamps
+    are kept apart so that event logs replay identically.
     """
 
     def __init__(self, rid: int, request: Request, clock: str = "ticks",
@@ -199,6 +205,7 @@ class RequestHandle:
         self.submitted_at: float | None = None
         self.admitted_at: float | None = None
         self.deferred_ticks = 0            # scheduler ticks spent deferred
+        self.wall: dict[str, float] = {}   # lifecycle stamp -> perf_counter
         self._tokens: list[int] = []
         self._finished: dict | None = None
 

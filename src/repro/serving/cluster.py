@@ -562,6 +562,7 @@ class _RuntimeBackend:
                                 for r in self.runtimes),
             "decode_round_ms": pct(rounds),
             "ttft_ms": pct(ttft),
+            "ep_rows_dropped": sum(r.ep_rows_dropped for r in self.runtimes),
         }
 
     def local_ratio(self) -> np.ndarray:

@@ -35,6 +35,7 @@ import numpy as np
 from repro.core.stats import ActivationStats
 from repro.models import moe as moe_mod
 from repro.models import transformer as tr
+from repro.serving.obs import SpanKind, host_span
 from repro.serving.sampling import sample_tokens
 
 
@@ -158,10 +159,11 @@ class ServingEngine:
                 # read their first token from last_buf next round (partial
                 # chunks scatter a value no decode round will ever gather).
                 # The sample position is the absolute last prompt index.
-                first = sample_tokens(
-                    logits, temps, seeds, offset + last_idx
-                ).astype(jnp.int32)
-                last_buf = last_buf.at[rows].set(first)
+                with jax.named_scope("head"):
+                    first = sample_tokens(
+                        logits, temps, seeds, offset + last_idx
+                    ).astype(jnp.int32)
+                    last_buf = last_buf.at[rows].set(first)
                 return last_buf, first, logits, pool, mstats
 
             def _dec(
@@ -190,10 +192,11 @@ class ServingEngine:
                     page_table=page_table,
                     origin=origin,
                 )
-                nxt = sample_tokens(logits, temps, seeds, pos).astype(
-                    jnp.int32
-                )
-                last_buf = last_buf.at[rows].set(nxt)
+                with jax.named_scope("head"):
+                    nxt = sample_tokens(logits, temps, seeds, pos).astype(
+                        jnp.int32
+                    )
+                    last_buf = last_buf.at[rows].set(nxt)
                 return last_buf, nxt, pool, mstats
 
             self._paged_fns[key] = (jax.jit(_chunk), jax.jit(_dec))
@@ -388,22 +391,23 @@ class ServingEngine:
         into a fresh params dict, so the old copy of a weight can be freed
         before the next is gathered: at full width a second EP copy of
         every expert would not fit beside the first and the master."""
-        self.placement = jax.tree.map(jnp.asarray, new_placement_stacked)
-        if self.dense_master is None:
-            return
-        s2e = self.placement.slot_to_expert
-        for k, master in self.dense_master.items():
-            if "router" not in master:
-                continue
-            for w in ("w1", "w3", "w2"):
-                old = self.params["groups"][k][w]
-                sharding = (
-                    old.sharding
-                    if isinstance(old.sharding, jax.sharding.NamedSharding)
-                    else None
-                )
-                del old
-                new = moe_mod.gather_expert_slots(master[w], s2e, sharding)
-                groups = dict(self.params["groups"])
-                groups[k] = {**groups[k], w: new}
-                self.params = {**self.params, "groups": groups}
+        with host_span(SpanKind.MIGRATE):
+            self.placement = jax.tree.map(jnp.asarray, new_placement_stacked)
+            if self.dense_master is None:
+                return
+            s2e = self.placement.slot_to_expert
+            for k, master in self.dense_master.items():
+                if "router" not in master:
+                    continue
+                for w in ("w1", "w3", "w2"):
+                    old = self.params["groups"][k][w]
+                    sharding = (
+                        old.sharding
+                        if isinstance(old.sharding, jax.sharding.NamedSharding)
+                        else None
+                    )
+                    del old
+                    new = moe_mod.gather_expert_slots(master[w], s2e, sharding)
+                    groups = dict(self.params["groups"])
+                    groups[k] = {**groups[k], w: new}
+                    self.params = {**self.params, "groups": groups}

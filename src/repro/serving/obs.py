@@ -1,12 +1,12 @@
 """Unified observability: span tracer, metrics registry, Chrome-trace
-export (``repro.serving.obs``).
+export, and the host spans of the serving tick (``repro.serving.obs``).
 
 Every subsystem grown so far — runtime, cluster, net, tiers, faults,
 workload — exposes its own ad-hoc ``metrics()`` dict, but none of them
 can answer *where one request's time went* (queue wait vs prefill vs
 decode vs cold-expert stalls) or *why the controller adopted a plan*
 (the Eq.-4 trade of ``C(P') + T_mig`` against ``C(P)``). This module
-adds the missing layer, in three parts:
+adds the missing layer, in four parts:
 
 * :class:`Tracer` — a span recorder on the owning backend's **model
   clock** (scheduler ticks for the runtime backend, modeled seconds for
@@ -30,15 +30,20 @@ adds the missing layer, in three parts:
   and completion data rides the existing async drain backlog, so
   tracing adds **zero host syncs** to the warmed zero-stall loop.
 
-* :class:`Registry` — metric primitives (:class:`Counter`,
-  :class:`Gauge`, :class:`Histogram`) plus namespaced **providers**:
-  each subsystem registers a callable producing its section
-  (``per_server``, ``perf``, ``net``, ``tiers``, ``faults``, ``obs``)
-  and :meth:`Registry.collect` assembles the one namespaced tree that
-  ``EdgeCluster.metrics()`` used to hand-merge from six call sites.
-  :func:`snapshot_diff` turns two collected trees into a windowed
-  reading (the registry-level analogue of ``TrafficMeter``'s
-  cumulative-counts diff).
+* :func:`host_span` — the host phases of one runtime tick
+  (``SpanKind.HOST``: ``serve.tick`` and its children ``serve.admit``,
+  ``serve.prefill``, ``serve.decode``, ``serve.drain`` > ``serve.wait``
+  / ``serve.review`` / ``serve.migrate``) as
+  ``jax.profiler.TraceAnnotation`` spans, on the **wall clock** the JAX
+  profiler shares with the device's operations. They are emitted
+  always, tracer or not, and recorded only while a profiler session is
+  active; the Tracer never sees them, so its records stay deterministic.
+
+* :class:`Registry` — namespaced **providers**: each subsystem
+  registers a callable producing its section (``per_server``, ``perf``,
+  ``net``, ``tiers``, ``faults``, ``obs``) and :meth:`Registry.collect`
+  assembles the one namespaced tree that ``EdgeCluster.metrics()`` used
+  to hand-merge from six call sites.
 
 * :meth:`Tracer.export` — Chrome trace-event JSON (the format Perfetto
   and ``chrome://tracing`` load): one track per server plus a
@@ -47,8 +52,9 @@ adds the missing layer, in three parts:
   across reruns. ``tools/trace_view.py`` prints the textual per-phase
   latency breakdown of an exported file.
 
-This module is dependency-light (numpy only), like ``api.py``: both
-execution worlds import it, never the other way around.
+This module is dependency-light (numpy only; :func:`host_span` imports
+JAX when called), like ``api.py``: both execution worlds import it,
+never the other way around.
 """
 
 from __future__ import annotations
@@ -90,8 +96,32 @@ class SpanKind:
     SYSTEM = (PLACEMENT_REVIEW, TRANSFER_TASK, FAULT, PREFETCH)
     ALL = REQUEST + SYSTEM
 
+    # host phases of one runtime tick, emitted by host_span as
+    # ``serve.<kind>`` on the profiler's wall clock (never Tracer records)
+    TICK = "tick"  # ServingRuntime.step
+    ADMIT = "admit"  # admission (paged lookup, page allocation, CoW)
+    PREFILL = "prefill"  # chunk inputs built on the host + the launch
+    DECODE = "decode"  # decode inputs built on the host + the launch
+    DRAIN = "drain"  # launched rounds applied: tokens, retirement, stats
+    WAIT = "wait"  # inside DRAIN: the host blocks on an unfinished step
+    REVIEW = "review"  # inside DRAIN: the placement controller's review
+    MIGRATE = "migrate"  # inside REVIEW: an adopted plan re-gathers experts
+    HOST = (TICK, ADMIT, PREFILL, DECODE, DRAIN, WAIT, REVIEW, MIGRATE)
+
 
 _REQUEST_KINDS = frozenset(SpanKind.REQUEST)
+_HOST_NAMES = {k: f"serve.{k}" for k in SpanKind.HOST}
+
+
+def host_span(kind: str):
+    """The profiler annotation ``serve.<kind>`` for one host phase of the
+    serving tick (``kind`` in ``SpanKind.HOST``), used as a context
+    manager. With no profiler session active, entering one costs about a
+    microsecond; under ``jax.profiler.trace`` it lands on the host plane,
+    on the same timeline as the device's operations."""
+    import jax  # here, so that the simulator's imports stay numpy-only
+
+    return jax.profiler.TraceAnnotation(_HOST_NAMES[kind])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -306,63 +336,8 @@ def as_tracer(trace, clock: str) -> Tracer:
 
 
 # ---------------------------------------------------------------------------
-# Metric primitives + the namespaced registry
+# The namespaced metrics registry
 # ---------------------------------------------------------------------------
-
-class Counter:
-    """A monotonic counter."""
-
-    __slots__ = ("value",)
-
-    def __init__(self, value: int = 0):
-        self.value = value
-
-    def inc(self, n: int = 1) -> int:
-        self.value += n
-        return self.value
-
-
-class Gauge:
-    """A last-value-wins scalar."""
-
-    __slots__ = ("value",)
-
-    def __init__(self, value: float = 0.0):
-        self.value = value
-
-    def set(self, v: float) -> None:
-        self.value = v
-
-
-class Histogram:
-    """A bounded value distribution: a deterministic systematic 1-in-2^k
-    subsample (no RNG — replays stay bit-identical), the same scheme the
-    runtime's latency reservoirs use. ``count`` is the total number of
-    observations, not the retained sample size."""
-
-    def __init__(self, max_items: int = 4096):
-        self.max_items = int(max_items)
-        self.count = 0
-        self._stride = 1
-        self._items: list[float] = []
-
-    def observe(self, x: float) -> None:
-        if self.count % self._stride == 0:
-            self._items.append(float(x))
-            if len(self._items) >= self.max_items:
-                self._items = self._items[::2]
-                self._stride *= 2
-        self.count += 1
-
-    def __iter__(self):
-        return iter(self._items)
-
-    def percentiles(self, qs=(50, 99)) -> dict:
-        if not self._items:
-            return {f"p{int(q)}": 0.0 for q in qs}
-        return {f"p{int(q)}": float(np.percentile(self._items, q))
-                for q in qs}
-
 
 class Registry:
     """Namespaced metrics tree assembled from per-subsystem providers.
@@ -400,20 +375,3 @@ class Registry:
                 out[ns] = v
         return out
 
-
-def snapshot_diff(before: dict, after: dict) -> dict:
-    """Windowed reading of two collected trees: numeric leaves become
-    ``after - before``, non-numeric and newly-appeared leaves pass
-    through from ``after``. Both inputs are left untouched."""
-    out = {}
-    for k, v in after.items():
-        prev = before.get(k)
-        if isinstance(v, dict) and isinstance(prev, dict):
-            out[k] = snapshot_diff(prev, v)
-        elif (isinstance(v, (int, float)) and not isinstance(v, bool)
-                and isinstance(prev, (int, float))
-                and not isinstance(prev, bool)):
-            out[k] = v - prev
-        else:
-            out[k] = v
-    return out

@@ -93,7 +93,7 @@ from repro.core.policies import PlacementController
 from repro.models import transformer as tr
 from repro.serving.api import EventType, Request, RequestHandle, SeqCounter
 from repro.serving.engine import ServingEngine
-from repro.serving.obs import NULL_TRACER, SpanKind, Tracer
+from repro.serving.obs import NULL_TRACER, SpanKind, Tracer, host_span
 from repro.serving.prefix_cache import PrefixMatch, RadixPrefixCache
 from repro.serving.sampling import sample_token_host, sample_tokens
 
@@ -146,14 +146,15 @@ class _Slot:
 
 @dataclasses.dataclass
 class _Pending:
-    """One launched-but-undrained round (the zero-stall backlog record).
+    """One launched-but-undrained round (the zero-stall backlog record;
+    the synchronous loop drains each one right after its launch).
 
     Holds the *device* result arrays of a decode round or a prefill chunk
-    call — their host copies are started at launch
-    (``copy_to_host_async``) and consumed one tick later, overlapped with
-    the next round's device step. ``rows`` maps batch row -> (slot index,
-    rid at launch); the rid guard makes drains robust to the slot having
-    retired (EOS lag) or been re-assigned meanwhile."""
+    call — under the zero-stall loop their host copies are started at
+    launch (``copy_to_host_async``) and consumed one tick later,
+    overlapped with the next round's device step. ``rows`` maps batch
+    row -> (slot index, rid at launch); the rid guard makes drains robust
+    to the slot having retired (EOS lag) or been re-assigned meanwhile."""
     kind: str                     # "decode" | "prefill"
     tick: int                     # tick the round was launched on
     rows: list                    # [(batch row, slot idx, rid)] — decode:
@@ -450,6 +451,10 @@ class ServingRuntime:
         self.prefix_tokens_skipped = 0  # prompt tokens never prefilled
         self.prefill_calls = 0        # jitted chunk calls issued
         self.chunks_executed = 0      # per-slot chunks consumed (compute)
+        self.prefill_tokens = 0       # valid prompt tokens those calls wrote
+        self.ep_rows_dropped = 0      # (row, expert) pairs the EP layer
+        #   dropped on a full capacity buffer, summed at drain (0 under
+        #   serving's dropless capacities)
         self.cow_copies = 0           # copy-on-write tail clones
         self.host_syncs = 0           # blocking host waits on device data
         #   (sync loop: one per decode round / final prefill chunk; the
@@ -458,11 +463,11 @@ class ServingRuntime:
         self.decode_round_s = Reservoir()   # per-round wall time of the
         #   decode segment (launch [+ backlog drain] [+ token fetch]);
         #   bounded: a systematic subsample survives long runs
-        self.ttft_s = Reservoir()      # wall-clock time to first token
+        self.ttft_s = Reservoir()      # wall-clock time to first token,
+        #   from the handles' ``wall`` stamps (enqueued -> first_token)
         self._finished_total = 0       # results drained via pop_finished()
         self.migrations: list = []
         self._pending: collections.deque[_Pending] = collections.deque()
-        self._t_enqueue: dict[int, float] = {}   # rid -> perf_counter()
         self._next_rid = 0
         self._origin_mode: str | None = None   # 'tagged' | 'untagged'
 
@@ -571,7 +576,9 @@ class ServingRuntime:
             seed=int(getattr(request, "seed", 0)),
             deadline=deadline))
         self.handles[rid] = handle
-        self._t_enqueue[rid] = time.perf_counter()
+        # first writer wins, like submitted_at: a failover re-admit keeps
+        # the original stamp, so its TTFT spans the crash
+        handle.wall.setdefault("enqueued", time.perf_counter())
         return handle
 
     def submit(self, prompt: np.ndarray, max_new_tokens: int,
@@ -637,7 +644,6 @@ class ServingRuntime:
             if r.rid == rid:
                 del self.queue[k]
                 self.handles.pop(rid, None)
-                self._t_enqueue.pop(rid, None)
                 self._enq_tick.pop(rid, None)
                 return 0
         for i, s in enumerate(self.slots):
@@ -647,7 +653,6 @@ class ServingRuntime:
                     self.page_table[i] = 0
                 self.slots[i] = None
                 self.handles.pop(rid, None)
-                self._t_enqueue.pop(rid, None)
                 self._enq_tick.pop(rid, None)
                 return len(s.tokens)
         return 0
@@ -679,6 +684,7 @@ class ServingRuntime:
             "rounds_timed": self.decode_round_s.count,
             "decode_round_ms": pct(self.decode_round_s),
             "ttft_ms": pct(self.ttft_s),
+            "ep_rows_dropped": self.ep_rows_dropped,
         }
 
     # ------------------------------------------------------------------
@@ -746,10 +752,10 @@ class ServingRuntime:
                    need=r.max_new_tokens)
         self.finished[r.rid] = np.zeros(0, np.int32)
         self.finished_at[r.rid] = self.ticks
-        self._t_enqueue.pop(r.rid, None)
         h = self.handles.get(r.rid)
         if h is None:
             return
+        h.wall["finished"] = time.perf_counter()
         latency = (self.ticks - h.submitted_at
                    if h.submitted_at is not None else None)
         h._emit(EventType.FINISHED, self.ticks,
@@ -830,6 +836,7 @@ class ServingRuntime:
                      prefix_skipped=m.tokens,
                      temperature=r.temperature, seed=r.seed)
         self.slots[i] = slot
+        self._stamp_admitted(r.rid)
         self._emit(r.rid, EventType.ADMITTED, slot=i, server=r.origin,
                    pages=len(pages))
         if self.tracer.enabled:
@@ -881,7 +888,8 @@ class ServingRuntime:
                 self.engine.params, jnp.asarray(tokens),
                 self.engine.placement,
                 self._origin_arg(r.origin for r in group))
-            self.engine._ingest(mstats)
+            self._await(logits, mstats)
+            self._ingest(mstats)
             idx = jnp.asarray(free[:len(group)], jnp.int32)
             self.pool = self._write_rows(self.pool, cache, idx)
             lg = np.asarray(logits)                                # [b, V]
@@ -893,6 +901,7 @@ class ServingRuntime:
                              eos=r.eos, launched=1,
                              temperature=r.temperature, seed=r.seed)
                 self.slots[free[j]] = slot
+                self._stamp_admitted(r.rid)
                 self._emit(r.rid, EventType.ADMITTED, slot=free[j],
                            server=r.origin)
                 if self.tracer.enabled:
@@ -906,6 +915,11 @@ class ServingRuntime:
             admitted += len(group)
         return admitted
 
+    def _stamp_admitted(self, rid: int) -> None:
+        h = self.handles.get(rid)
+        if h is not None:
+            h.wall["admitted"] = time.perf_counter()
+
     def _append_token(self, slot: _Slot, tok: int) -> None:
         """Record one drained token: handle events, time-to-first-token,
         and EOS stop detection (the stop shrinks ``need`` to the tokens
@@ -915,9 +929,10 @@ class ServingRuntime:
         slot.last = tok
         slot.tokens.append(tok)
         if len(slot.tokens) == 1:
-            t0 = self._t_enqueue.pop(slot.rid, None)
-            if t0 is not None:
-                self.ttft_s.append(time.perf_counter() - t0)
+            h = self.handles.get(slot.rid)
+            if h is not None and "first_token" not in h.wall:
+                now = h.wall["first_token"] = time.perf_counter()
+                self.ttft_s.append(now - h.wall["enqueued"])
         self._emit(slot.rid, EventType.TOKEN, token=tok)
         if (slot.eos is not None and tok == slot.eos
                 and len(slot.tokens) < slot.need):
@@ -954,6 +969,7 @@ class ServingRuntime:
         h = self.handles.get(slot.rid)
         if h is None:
             return
+        h.wall["finished"] = time.perf_counter()
         latency = (self.ticks - h.submitted_at
                    if h.submitted_at is not None else None)
         wait = (h.admitted_at - h.submitted_at
@@ -987,97 +1003,102 @@ class ServingRuntime:
         decode batch from the next round on. One batched host transfer of
         the final-position logits is issued per chunk call (lazily — only
         when some slot finished); the zero-stall loop starts it
-        asynchronously and consumes it at the next tick's drain."""
-        bs = self.block_size
+        asynchronously and consumes it at the next tick's drain, the
+        synchronous loop drains each call right after its launch."""
         for _ in range(self.chunks_per_tick):
-            act = [i for i, s in enumerate(self.slots)
-                   if s is not None and s.prefilling]
-            if not act:
+            with host_span(SpanKind.PREFILL):
+                launched = self._launch_chunk()
+            if not launched:
                 return
-            if self.compact_prefill:
-                B = min(self.max_slots,
-                        1 << max(len(act) - 1, 0).bit_length())
-                row_slots: list[int | None] = act + [None] * (B - len(act))
-            else:
-                B = self.max_slots
-                row_slots = [i if i in act else None for i in range(B)]
-            rows = np.full((B,), self.max_slots, np.int32)   # pad rows ->
-            #   the last-token buffer's trailing scratch entry
-            toks = np.zeros((B, bs), np.int32)
-            mask = np.zeros((B, bs), np.float32)
-            offs = np.zeros((B,), np.int32)
-            lidx = np.zeros((B,), np.int32)
-            wb = np.zeros((B,), np.int32)      # idle rows -> null block 0
-            tbl = np.zeros((B, self.max_pages), np.int32)
-            temps = np.zeros((B,), np.float32)
-            seeds = np.zeros((B,), np.uint32)
-            finals: list[tuple[int, int, int]] = []   # (row, slot, rid)
-            for j, i in enumerate(row_slots):
-                if i is None:
-                    continue
-                s = self.slots[i]
-                rows[j] = i
-                T = len(s.prompt)
-                c0 = s.filled
-                valid = min(bs, T - c0)
-                toks[j, :valid] = s.prompt[c0:c0 + valid]
-                mask[j, :valid] = 1.0
-                offs[j] = c0
-                wb[j] = s.pages[c0 // bs]
-                tbl[j] = self.page_table[i]
-                temps[j] = s.temperature
-                seeds[j] = s.seed
-                final = c0 + valid >= T
-                lidx[j] = (T - 1 - c0) if final else bs - 1
-                s.filled += valid
-                if final:
-                    # launch-side bookkeeping: the slot joins this tick's
-                    # decode batch (its first token is already seeded into
-                    # the device last-token buffer by the chunk call)
-                    s.pos = T
-                    s.launched = 1
-                    finals.append((j, i, s.rid))
-            org = self._origin_arg(
-                self.slots[i].origin if i is not None else None
-                for i in row_slots)
-            exe = (self.engine.paged_executable(
-                       "chunk", bs, self.max_pages, B, org is not None)
-                   if self.warmup else None)
-            fn = exe if exe is not None else self._chunk_fn
-            self._last_buf, first, logits, self.pool, mstats = fn(
-                self.engine.params, self.pool, self._last_buf,
-                jnp.asarray(rows), jnp.asarray(toks), jnp.asarray(tbl),
-                jnp.asarray(wb), jnp.asarray(offs), jnp.asarray(lidx),
-                self.engine.placement, jnp.asarray(mask),
-                jnp.asarray(temps), jnp.asarray(seeds), org)
-            self.prefill_calls += 1
-            self.prefill_rows += B
-            self.chunks_executed += len(act)
-            if self.tracer.enabled:
-                # batch-level span from launch-side metadata only (slot
-                # counts, tick number — all host-known): tracing adds no
-                # device reads, so the zero-stall loop stays zero-stall
-                self.tracer.span(SpanKind.PREFILL_CHUNK, self.ticks,
-                                 self.ticks + 1, server=self.tracer_server,
-                                 rows=len(act), batch=B,
-                                 finals=len(finals))
-            if self.warmup:
-                if finals:
-                    self._copy_async(logits)
-                    self._copy_async(first)
-                self._copy_async(mstats)
-                self._pending.append(_Pending(
-                    "prefill", self.ticks, finals,
-                    logits=logits if finals else None,
-                    first=first if finals else None, mstats=mstats))
+            if not self.warmup:
+                self._drain_backlog()
+
+    def _launch_chunk(self) -> bool:
+        """Build one chunk call's inputs on the host, launch it and queue
+        its round record; returns whether any slot was prefilling."""
+        bs = self.block_size
+        act = [i for i, s in enumerate(self.slots)
+               if s is not None and s.prefilling]
+        if not act:
+            return False
+        if self.compact_prefill:
+            B = min(self.max_slots,
+                    1 << max(len(act) - 1, 0).bit_length())
+            row_slots: list[int | None] = act + [None] * (B - len(act))
+        else:
+            B = self.max_slots
+            row_slots = [i if i in act else None for i in range(B)]
+        rows = np.full((B,), self.max_slots, np.int32)   # pad rows ->
+        #   the last-token buffer's trailing scratch entry
+        toks = np.zeros((B, bs), np.int32)
+        mask = np.zeros((B, bs), np.float32)
+        offs = np.zeros((B,), np.int32)
+        lidx = np.zeros((B,), np.int32)
+        wb = np.zeros((B,), np.int32)      # idle rows -> null block 0
+        tbl = np.zeros((B, self.max_pages), np.int32)
+        temps = np.zeros((B,), np.float32)
+        seeds = np.zeros((B,), np.uint32)
+        finals: list[tuple[int, int, int]] = []   # (row, slot, rid)
+        for j, i in enumerate(row_slots):
+            if i is None:
                 continue
-            self.engine._ingest(mstats)
+            s = self.slots[i]
+            rows[j] = i
+            T = len(s.prompt)
+            c0 = s.filled
+            valid = min(bs, T - c0)
+            toks[j, :valid] = s.prompt[c0:c0 + valid]
+            mask[j, :valid] = 1.0
+            offs[j] = c0
+            wb[j] = s.pages[c0 // bs]
+            tbl[j] = self.page_table[i]
+            temps[j] = s.temperature
+            seeds[j] = s.seed
+            final = c0 + valid >= T
+            lidx[j] = (T - 1 - c0) if final else bs - 1
+            s.filled += valid
+            self.prefill_tokens += valid
+            if final:
+                # launch-side bookkeeping: the slot joins this tick's
+                # decode batch (its first token is already seeded into
+                # the device last-token buffer by the chunk call)
+                s.pos = T
+                s.launched = 1
+                finals.append((j, i, s.rid))
+        org = self._origin_arg(
+            self.slots[i].origin if i is not None else None
+            for i in row_slots)
+        exe = (self.engine.paged_executable(
+                   "chunk", bs, self.max_pages, B, org is not None)
+               if self.warmup else None)
+        fn = exe if exe is not None else self._chunk_fn
+        self._last_buf, first, logits, self.pool, mstats = fn(
+            self.engine.params, self.pool, self._last_buf,
+            jnp.asarray(rows), jnp.asarray(toks), jnp.asarray(tbl),
+            jnp.asarray(wb), jnp.asarray(offs), jnp.asarray(lidx),
+            self.engine.placement, jnp.asarray(mask),
+            jnp.asarray(temps), jnp.asarray(seeds), org)
+        self.prefill_calls += 1
+        self.prefill_rows += B
+        self.chunks_executed += len(act)
+        if self.tracer.enabled:
+            # batch-level span from launch-side metadata only (slot
+            # counts, tick number — all host-known): tracing adds no
+            # device reads, so the zero-stall loop stays zero-stall
+            self.tracer.span(SpanKind.PREFILL_CHUNK, self.ticks,
+                             self.ticks + 1, server=self.tracer_server,
+                             rows=len(act), batch=B,
+                             finals=len(finals))
+        if self.warmup:
             if finals:
-                self.host_syncs += 1
-                lg = np.asarray(logits)
-                fi = np.asarray(first)
-                for j, i, rid in finals:
-                    self._finish_prefill(i, rid, lg[j], int(fi[j]))
+                self._copy_async(logits)
+                self._copy_async(first)
+            self._copy_async(mstats)
+        self._pending.append(_Pending(
+            "prefill", self.ticks, finals,
+            logits=logits if finals else None,
+            first=first if finals else None, mstats=mstats))
+        return True
 
     def _finish_prefill(self, i: int, rid: int, logits_row,
                         first_tok: int) -> None:
@@ -1119,7 +1140,17 @@ class ServingRuntime:
         near-empty pool stops paying for ``max_slots`` rows of garbage
         decode. Composition is launch-driven: a slot rides while
         ``launched < need``, so length stops never wait for a drained
-        token and EOS stops cost at most one speculative round."""
+        token and EOS stops cost at most one speculative round. The
+        synchronous loop drains the round right after its launch."""
+        with host_span(SpanKind.DECODE):
+            launched = self._launch_decode()
+        if launched and not self.warmup:
+            self._drain_backlog()
+        return launched
+
+    def _launch_decode(self) -> bool:
+        """Build one decode round's inputs on the host, launch it and
+        queue its round record; returns whether any slot was decoding."""
         act = [i for i, s in enumerate(self.slots)
                if s is not None and not s.prefilling
                and s.launched < s.need]
@@ -1149,7 +1180,7 @@ class ServingRuntime:
             s.launched += 1
             launched.append((j, i, s.rid))
         if self.tracer.enabled:
-            # launch-side only (see _prefill_round): no extra host syncs
+            # launch-side only (see _launch_chunk): no extra host syncs
             self.tracer.span(SpanKind.DECODE_ROUND, self.ticks,
                              self.ticks + 1, server=self.tracer_server,
                              rows=len(act), batch=B)
@@ -1179,21 +1210,12 @@ class ServingRuntime:
                 jnp.asarray(rows), jnp.asarray(pos), jnp.asarray(tbl),
                 self.engine.placement, jnp.asarray(mask),
                 jnp.asarray(temps), jnp.asarray(seeds), org)
-            self.decode_rows += B
             if self.warmup:
                 # zero-stall: round k+1 chains on device through the
                 # last-token buffer; the host copy of this round's tokens
                 # runs under the next device step and drains one tick late
                 self._copy_async(nxt)
                 self._copy_async(mstats)
-                self._pending.append(_Pending("decode", self.ticks,
-                                              launched, nxt=nxt,
-                                              mstats=mstats))
-                return True
-            self.engine._ingest(mstats)
-            self.host_syncs += 1
-            self._drain_tokens(launched, np.asarray(nxt),
-                               self._round_local_frac(mstats))
         else:
             cur = np.zeros((B, 1), np.int32)
             for j, i in enumerate(row_slots):
@@ -1203,19 +1225,14 @@ class ServingRuntime:
                 self.engine.params, self.pool, jnp.asarray(cur),
                 jnp.asarray(pos), self.engine.placement, jnp.asarray(mask),
                 org)
-            self.decode_rows += B
-            self.engine._ingest(mstats)
-            self.host_syncs += 1
             if np.any(temps > 0.0):
-                nxt = np.asarray(sample_tokens(
-                    logits, jnp.asarray(temps), jnp.asarray(seeds),
-                    jnp.asarray(pos)), np.int32)
+                nxt = sample_tokens(logits, jnp.asarray(temps),
+                                    jnp.asarray(seeds), jnp.asarray(pos))
             else:
-                nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
-            self._drain_tokens(launched, nxt,
-                               self._round_local_frac(mstats))
-        self.rounds += 1
-        self._maybe_review()
+                nxt = jnp.argmax(logits, -1)
+        self.decode_rows += B
+        self._pending.append(_Pending("decode", self.ticks, launched,
+                                      nxt=nxt, mstats=mstats))
         return True
 
     # -- the zero-stall backlog ----------------------------------------
@@ -1227,12 +1244,34 @@ class ServingRuntime:
             if hasattr(leaf, "copy_to_host_async"):
                 leaf.copy_to_host_async()
 
+    @staticmethod
+    def _await(*results) -> bool:
+        """Block until the device has computed every array of ``results``
+        (pytrees; None is skipped), inside a ``serve.wait`` span when any
+        is still running — the one span in which the host waits on the
+        device. Returns whether it had to wait."""
+        late = [a for a in jax.tree.leaves(results)
+                if hasattr(a, "is_ready") and not a.is_ready()]
+        if not late:
+            return False
+        with host_span(SpanKind.WAIT):
+            jax.block_until_ready(late)
+        return True
+
     def _fetch(self, x) -> np.ndarray:
         """Drain-side host materialization; counts a host-sync point when
-        the async copy has not finished (a genuine stall)."""
-        if hasattr(x, "is_ready") and not x.is_ready():
+        the result has not landed (a genuine stall) and, in the
+        synchronous loop, always (it drains in the launch's own tick)."""
+        if self._await(x) or not self.warmup:
             self.host_syncs += 1
         return np.asarray(x)
+
+    def _ingest(self, mstats) -> None:
+        """Feed a round's gating statistics to the engine and add the EP
+        layer's capacity drops to ``ep_rows_dropped``."""
+        self.engine._ingest(mstats)
+        if mstats is not None and "dropped" in mstats:
+            self.ep_rows_dropped += int(np.asarray(mstats["dropped"]).sum())
 
     @staticmethod
     def _round_local_frac(mstats) -> float | None:
@@ -1265,26 +1304,27 @@ class ServingRuntime:
             self._retire_if_done(i)
 
     def _drain_one(self, p: _Pending) -> None:
-        self.engine._ingest(p.mstats)
+        self._await(p.mstats)
+        self._ingest(p.mstats)
         if p.kind == "decode":
             self._drain_tokens(p.rows, self._fetch(p.nxt),
                                self._round_local_frac(p.mstats))
             self.rounds += 1
             self._maybe_review()
-        else:
-            if p.rows:
-                lg = self._fetch(p.logits)
-                fi = self._fetch(p.first)
-                for j, i, rid in p.rows:
-                    self._finish_prefill(i, rid, lg[j], int(fi[j]))
+        elif p.rows:
+            lg = self._fetch(p.logits)
+            fi = np.asarray(p.first)
+            for j, i, rid in p.rows:
+                self._finish_prefill(i, rid, lg[j], int(fi[j]))
 
     def _drain_backlog(self, before_tick: int | None = None) -> None:
         """Drain pending round records in launch order — all of them, or
         only those launched before ``before_tick`` (the steady-state call
         leaves the current tick's in-flight round pending)."""
-        while self._pending and (before_tick is None
-                                 or self._pending[0].tick < before_tick):
-            self._drain_one(self._pending.popleft())
+        with host_span(SpanKind.DRAIN):
+            while self._pending and (before_tick is None
+                                     or self._pending[0].tick < before_tick):
+                self._drain_one(self._pending.popleft())
 
     def flush(self) -> None:
         """Force-drain the zero-stall backlog: after this, every launched
@@ -1297,7 +1337,8 @@ class ServingRuntime:
         ctrl = self.controller
         if ctrl is None:
             return
-        dec = ctrl.review_and_apply(self.rounds, self.engine)
+        with host_span(SpanKind.REVIEW):
+            dec = ctrl.review_and_apply(self.rounds, self.engine)
         if dec is not None and dec.applied:
             self.migrations.append(dec.diag)
 
@@ -1355,19 +1396,21 @@ class ServingRuntime:
         launch one decode round, then (warmup mode) drain the previous
         tick's backlog while this tick's round runs on device. Returns
         True while there is (or was) work."""
-        had_work = (bool(self.queue) or self.active > 0
-                    or bool(self._pending))
-        self.ticks += 1
-        self._admit()
-        if self.paged:
-            self._prefill_round()
-        t0 = time.perf_counter()
-        launched = self._decode_round()
-        if self.warmup:
-            self._drain_backlog(self.ticks)
-        if launched:
-            self.decode_round_s.append(time.perf_counter() - t0)
-        return had_work
+        with host_span(SpanKind.TICK):
+            had_work = (bool(self.queue) or self.active > 0
+                        or bool(self._pending))
+            self.ticks += 1
+            with host_span(SpanKind.ADMIT):
+                self._admit()
+            if self.paged:
+                self._prefill_round()
+            t0 = time.perf_counter()
+            launched = self._decode_round()
+            if self.warmup:
+                self._drain_backlog(self.ticks)
+            if launched:
+                self.decode_round_s.append(time.perf_counter() - t0)
+            return had_work
 
     def run(self) -> dict[int, np.ndarray]:
         """Serve until queue, slots and backlog drain; returns

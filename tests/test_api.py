@@ -391,14 +391,22 @@ def test_cluster_sim_slo_and_step():
 
 
 # ---------------------------------------------------------------------------
-# bench-serving/v7 schema (satellite): cluster + net + perf + faults +
-# tiers + workload
+# The bench-serving schema, at its current version: cluster + net + perf +
+# faults + tiers + workload + obs
 # ---------------------------------------------------------------------------
 
-def _v7_doc():
+def _schema():
+    import os
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from benchmarks import schema
+    return schema
+
+
+def _bench_doc():
     pair = {"cache": 2, "nocache": 1}
     return {
-        "schema": "bench-serving/v7", "mode": "smoke",
+        "schema": _schema().SCHEMA_NAME, "mode": "smoke",
         "metrics": {
             "admitted_concurrency": dict(pair),
             "prefill_chunks_executed": dict(pair),
@@ -434,6 +442,7 @@ def _v7_doc():
                 "rounds_timed": 40,
                 "decode_round_ms": {"p50": 3.5, "p99": 9.0},
                 "ttft_ms": {"p50": 120.0, "p99": 250.0},
+                "ep_rows_dropped": 0,
             },
             "faults": {
                 "injected": 1,
@@ -476,16 +485,22 @@ def _v7_doc():
                 "phases": {"flash": {"requests": 270, "sheds": 140}},
                 "replay_identical": 1,
             },
+            "obs": {
+                "enabled": 1,
+                "clock": "seconds",
+                "events": 3528,
+                "dropped_events": 0,
+                "overhead_ms": 12.4,
+                "span_counts": {"QUEUE_WAIT": 60, "PLACEMENT_REVIEW": 16},
+                "replay_identical": 1,
+            },
         },
     }
 
 
-def test_schema_v7_accepts_and_rejects():
-    import sys
-    import os
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    from benchmarks.schema import BenchSchemaError, validate_bench_serving
-    assert validate_bench_serving(_v7_doc())
+def test_schema_accepts_and_rejects():
+    schema = _schema()
+    assert schema.validate_bench_serving(_bench_doc())
     for mutate in (
         lambda d: d["metrics"].pop("cluster"),
         lambda d: d["metrics"]["cluster"].pop("per_server_local_ratio"),
@@ -540,8 +555,18 @@ def test_schema_v7_accepts_and_rejects():
             goodput_tokens_per_s=10.0),            # lost to FIFO: gate fails
         lambda d: d["metrics"]["workload"]["ttft_s"].pop("p99"),
         lambda d: d["metrics"]["workload"].update(sheds=-1),
+        lambda d: d["metrics"]["perf"].pop("ep_rows_dropped"),
+        lambda d: d["metrics"]["perf"].update(ep_rows_dropped=-1),
+        lambda d: d["metrics"].pop("obs"),                       # v8
+        lambda d: d["metrics"]["obs"].pop("overhead_ms"),
+        lambda d: d["metrics"]["obs"].update(clock="wall"),
+        lambda d: d["metrics"]["obs"].update(span_counts={}),
+        lambda d: d["metrics"]["obs"].update(span_counts={"SHED": -1}),
+        lambda d: d["metrics"]["obs"].update(events=0),          # empty run
+        lambda d: d["metrics"]["obs"].update(dropped_events=3),
+        lambda d: d["metrics"]["obs"].update(replay_identical=0),
     ):
-        doc = _v7_doc()
+        doc = _bench_doc()
         mutate(doc)
-        with pytest.raises(BenchSchemaError):
-            validate_bench_serving(doc)
+        with pytest.raises(schema.BenchSchemaError):
+            schema.validate_bench_serving(doc)

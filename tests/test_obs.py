@@ -4,8 +4,7 @@ Covers the ``repro.serving.obs`` contracts end to end:
 
 * tracer primitives — recording, the max-events drop cap, the
   ``as_tracer`` normalization, the zero-allocation NULL_TRACER;
-* registry primitives — provider collection, None-omission,
-  ``snapshot_diff``, the deterministic Histogram subsample;
+* the registry — provider collection, None-omission;
 * trace determinism — a faulted + migrating + tiered sim scenario
   rerun exports **byte-identical** Chrome-trace JSON, and the cluster
   event stream keeps a seq-stamped stable total order (the
@@ -28,9 +27,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.serving.api import EventType, Request
-from repro.serving.obs import (NULL_TRACER, Counter, Gauge, Histogram,
-                               Registry, SpanKind, Tracer, as_tracer,
-                               snapshot_diff)
+from repro.serving.obs import (NULL_TRACER, Registry, SpanKind, Tracer,
+                               as_tracer)
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -102,36 +100,6 @@ def test_registry_collects_in_order_and_omits_none():
     assert reg.collect()["b"] == {"x": 9}
     with pytest.raises(TypeError, match="callable"):
         reg.register("d", {"not": "callable"})
-
-
-def test_counter_gauge_and_snapshot_diff():
-    c, g = Counter(), Gauge()
-    c.inc()
-    c.inc(4)
-    g.set(2.5)
-    assert c.value == 5 and g.value == 2.5
-    before = {"a": {"n": 5, "flag": True, "name": "x"}, "t": 1.0}
-    after = {"a": {"n": 9, "flag": False, "name": "y"}, "t": 3.5, "new": 7}
-    d = snapshot_diff(before, after)
-    assert d["a"]["n"] == 4 and d["t"] == 2.5
-    assert d["a"]["flag"] is False and d["a"]["name"] == "y"  # pass-through
-    assert d["new"] == 7                       # newly-appeared leaf
-    assert before["a"]["n"] == 5               # inputs untouched
-
-
-def test_histogram_deterministic_subsample():
-    def fill(n):
-        h = Histogram(max_items=64)
-        for i in range(n):
-            h.observe(float(i % 97))
-        return h
-
-    a, b = fill(1000), fill(1000)
-    assert a.count == b.count == 1000
-    assert list(a) == list(b)                  # no RNG: identical retained
-    assert len(list(a)) <= 64
-    p = a.percentiles((50, 99))
-    assert 0.0 <= p["p50"] <= p["p99"] <= 96.0
 
 
 # ---------------------------------------------------------------------------
