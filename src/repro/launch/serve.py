@@ -43,7 +43,9 @@ def ep_capacities(rows: int, top_k: int, n_ep: int) -> tuple[int, int]:
     """Dropless EP capacities for steps of at most ``rows`` token rows:
     ``C`` holds one source rank's whole share of rows x ``top_k`` bound for
     a single destination, and ``C2`` every row of the step, since a slot
-    holds one expert and a row sends it at most one (row, expert) pair."""
+    holds one expert and a row sends it at most one (row, expert) pair.
+    Both are upper bounds: each call clamps them by its own rows, so a
+    32-row decode step computes 32 slot rows, not ``rows``."""
     return max(8, -(-rows // n_ep) * top_k), max(8, rows)
 
 

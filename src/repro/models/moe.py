@@ -46,6 +46,9 @@ class EPSpec:
     mesh_axes: tuple[str, ...]   # all mesh axis names, in order
     n_ep: int                    # number of EP ranks (product of axes sizes)
     slots: int                   # S: expert slots per rank
+    # Both capacities are upper bounds: each call clamps them to what its
+    # own rows can fill (``_row_bound``), so a step never computes buffer
+    # rows that no input reaches; a value below that bound drops as set.
     capacity: int                # C: per (src->dst) a2a send capacity
     slot_capacity: int           # C2: per-slot compute capacity (recv side)
 
@@ -272,6 +275,21 @@ def _dropped(keys, n_buckets, keep):
             - jnp.sum(keep, dtype=jnp.int32))
 
 
+def _row_bound(cap: int, pairs: int) -> int:
+    """A capacity clamped to the most (row, expert) pairs a call can put in
+    one bucket: ``pairs`` rounded up to the sublane tile (8), never past
+    ``cap``."""
+    return min(cap, -(-pairs // 8) * 8)
+
+
+def _ep_counters(dropped, filled, S, C2):
+    """This rank's pairs dropped, slot rows filled with a live pair and
+    slot rows computed (S x C2), as one vector: one psum carries all
+    three."""
+    return jnp.stack([dropped, filled.astype(jnp.int32),
+                      jnp.int32(S * C2)])
+
+
 def _local_slots(p):
     """Per-device slice of the EP weights inside shard_map ([1,S,...]→[S,...])."""
     return {k: p[k][0] for k in ("w1", "w3", "w2")}
@@ -288,7 +306,11 @@ def _ep_dispatch_local(h_loc, p, placement, cfg, spec: EPSpec,
     — the serving runtime always passes the true origin)."""
     R, D = h_loc.shape
     E, K = cfg.num_experts, cfg.top_k
-    n_ep, S, C, C2 = spec.n_ep, spec.slots, spec.capacity, spec.slot_capacity
+    n_ep, S = spec.n_ep, spec.slots
+    # a source row sends at most K pairs to one rank, and a slot (one
+    # expert) takes at most one pair of each row that reaches it
+    C = _row_bound(spec.capacity, R * K)
+    C2 = _row_bound(spec.slot_capacity, n_ep * min(C, R))
     my = lax.axis_index(spec.axes)
     with jax.named_scope("moe.route"):
         probs, topv, topi = route(p["router"], h_loc, K)
@@ -302,8 +324,13 @@ def _ep_dispatch_local(h_loc, p, placement, cfg, spec: EPSpec,
         dest = jnp.where(keep, tgt[order] * C + pos, n_ep * C)    # OOB = drop
         buf_x = jnp.zeros((n_ep * C, D), h_loc.dtype).at[dest].set(
             h_loc[flat_src[order]], mode="drop")
+        # a pair of a masked-out row travels as expert + E, so the
+        # receiver can tell the live pairs it computes
+        code = flat_e.astype(jnp.int32)
+        if m_loc is not None:
+            code = jnp.where(m_loc[flat_src] > 0, code, code + E)
         buf_e = jnp.full((n_ep * C,), -1, jnp.int32).at[dest].set(
-            flat_e[order].astype(jnp.int32), mode="drop")
+            code[order], mode="drop")
 
         recv_x = lax.all_to_all(buf_x.reshape(n_ep, C, D), spec.axes, 0, 0,
                                 tiled=False)
@@ -313,6 +340,8 @@ def _ep_dispatch_local(h_loc, p, placement, cfg, spec: EPSpec,
         # --- receiver: slot bucketing into the slot buffer ---
         xs = recv_x.reshape(n_ep * C, D)
         es = recv_e.reshape(n_ep * C)
+        live = es < E
+        es = jnp.where(live, es, es - E)
         slot = jnp.where(es >= 0,
                          placement.expert_to_slot[my, jnp.maximum(es, 0)], -1)
         slot_key = jnp.where(slot >= 0, slot, S).astype(jnp.int32)
@@ -355,9 +384,10 @@ def _ep_dispatch_local(h_loc, p, placement, cfg, spec: EPSpec,
     aux = lax.pmean(aux_load_balance_loss(probs, topi, E), spec.mesh_axes)
     # (row, expert) pairs dropped at either capacity: C on the send side,
     # C2 on the receive side (padding rows included)
-    dropped = lax.psum(_dropped(tgt, n_ep, keep)
-                       + _dropped(slot_key, S, keep2), spec.mesh_axes)
-    return out, counts, local, aux, dropped
+    ep = lax.psum(_ep_counters(
+        _dropped(tgt, n_ep, keep) + _dropped(slot_key, S, keep2),
+        jnp.sum(keep2 & live[order2]), S, C2), spec.mesh_axes)
+    return out, counts, local, aux, ep
 
 
 def _ep_gather_local(h_loc, m_loc, p, placement, cfg, spec: EPSpec,
@@ -372,7 +402,7 @@ def _ep_gather_local(h_loc, m_loc, p, placement, cfg, spec: EPSpec,
     requests "arrive at" the first EP rank of their batch shard."""
     R, D = h_loc.shape
     E, K = cfg.num_experts, cfg.top_k
-    n_ep, S, C2 = spec.n_ep, spec.slots, spec.slot_capacity
+    n_ep, S = spec.n_ep, spec.slots
     my = lax.axis_index(spec.axes)
     with jax.named_scope("moe.dispatch"):
         h_all = (lax.all_gather(h_loc, gather_axes, tiled=True)
@@ -380,6 +410,9 @@ def _ep_gather_local(h_loc, m_loc, p, placement, cfg, spec: EPSpec,
         m_all = (lax.all_gather(m_loc, gather_axes, tiled=True)
                  if gather_axes else m_loc)                    # [Btok]
     Btok = h_all.shape[0]
+    # a row's top-k experts are distinct and each resolves to one slot of
+    # one rank, so a slot takes at most one pair of each row
+    C2 = _row_bound(spec.slot_capacity, Btok)
     with jax.named_scope("moe.route"):
         probs, topv, topi = route(p["router"], h_all, K)
     with jax.named_scope("moe.dispatch"):
@@ -432,15 +465,27 @@ def _ep_gather_local(h_loc, m_loc, p, placement, cfg, spec: EPSpec,
                    if a not in spec.axes and a not in gather_axes)
     # each EP rank buckets only the pairs bound for it: their C2 drops
     # add up over the EP axes (padding rows included)
-    dropped = lax.psum(_dropped(slot_key, S, keep2), spec.axes)
+    ep = lax.psum(_ep_counters(_dropped(slot_key, S, keep2),
+                               jnp.sum(keep2 & (valid[order2] > 0)), S, C2),
+                  spec.axes)
     if non_ep:
         counts = lax.psum(counts, non_ep)
-        dropped = lax.psum(dropped, non_ep)
+        ep = lax.psum(ep, non_ep)
     local = lax.pmean(
         jnp.sum((tgt == src_ep[flat_src]).astype(jnp.float32) * valid)
         / jnp.maximum(jnp.sum(valid), 1.0), spec.mesh_axes)
     aux = lax.pmean(aux_load_balance_loss(probs, topi, E), spec.mesh_axes)
-    return out, counts, local, aux, dropped
+    return out, counts, local, aux, ep
+
+
+def _ep_stats(counts, local, aux, ep):
+    """The layer's stats dict. ``dropped``: (row, expert) pairs left out
+    for want of capacity; ``rows_filled``: kept pairs of live rows;
+    ``rows_computed``: the slot-buffer rows the expert FFN ran (S x C2,
+    summed over ranks)."""
+    return {"counts": counts.sum(0), "counts_per_rank": counts,
+            "aux_loss": aux, "local_frac": local, "dropped": ep[0],
+            "rows_filled": ep[1], "rows_computed": ep[2]}
 
 
 def moe_apply_ep(p, cfg, x, *, mesh, spec: EPSpec, placement: EPPlacement,
@@ -494,19 +539,17 @@ def moe_apply_ep(p, cfg, x, *, mesh, spec: EPSpec, placement: EPPlacement,
 
         def body3(h3, p_loc, pl_loc):
             b_, t_, d_ = h3.shape
-            o, c, l, a, dr = _ep_dispatch_local(
+            o, c, l, a, ep = _ep_dispatch_local(
                 h3.reshape(b_ * t_, d_), p_loc, pl_loc, cfg, spec,
                 use_kernel)
-            return o.reshape(b_, t_, d_), c, l, a, dr
+            return o.reshape(b_, t_, d_), c, l, a, ep
 
         fn = jax.shard_map(body3, mesh=mesh,
                            in_specs=(rows_spec3, wspec, pl_spec),
                            out_specs=(rows_spec3, P(), P(), P(), P()),
                            check_vma=False)
-        out, counts, local, aux, dropped = fn(h, p_in, placement)
-        stats = {"counts": counts.sum(0), "counts_per_rank": counts,
-                 "aux_loss": aux, "local_frac": local, "dropped": dropped}
-        return x + out.astype(x.dtype), stats
+        out, counts, local, aux, ep = fn(h, p_in, placement)
+        return x + out.astype(x.dtype), _ep_stats(counts, local, aux, ep)
     else:
         rows_spec = P(spec.dispatch_row_axes, None)
 
@@ -557,12 +600,10 @@ def moe_apply_ep(p, cfg, x, *, mesh, spec: EPSpec, placement: EPPlacement,
                        in_specs=(rows_spec, mask_spec, mask_spec, wspec,
                                  pl_spec),
                        out_specs=out_specs, check_vma=False)
-    out_rows, counts, local, aux, dropped = fn(rows, mask_rows, origin_rows,
-                                               p_in, placement)
+    out_rows, counts, local, aux, ep = fn(rows, mask_rows, origin_rows,
+                                          p_in, placement)
     out = out_rows[:B * T].reshape(B, T, D)
     if batch_row_axes and B % n_batch == 0:
         out = lax.with_sharding_constraint(
             out, NamedSharding(mesh, P(batch_row_axes, None, None)))
-    stats = {"counts": counts.sum(0), "counts_per_rank": counts,
-             "aux_loss": aux, "local_frac": local, "dropped": dropped}
-    return x + out.astype(x.dtype), stats
+    return x + out.astype(x.dtype), _ep_stats(counts, local, aux, ep)

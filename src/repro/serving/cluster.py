@@ -563,6 +563,9 @@ class _RuntimeBackend:
             "decode_round_ms": pct(rounds),
             "ttft_ms": pct(ttft),
             "ep_rows_dropped": sum(r.ep_rows_dropped for r in self.runtimes),
+            "ep_rows_computed": sum(r.ep_rows_computed
+                                    for r in self.runtimes),
+            "ep_rows_filled": sum(r.ep_rows_filled for r in self.runtimes),
         }
 
     def local_ratio(self) -> np.ndarray:

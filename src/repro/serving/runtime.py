@@ -455,6 +455,9 @@ class ServingRuntime:
         self.ep_rows_dropped = 0      # (row, expert) pairs the EP layer
         #   dropped on a full capacity buffer, summed at drain (0 under
         #   serving's dropless capacities)
+        self.ep_rows_computed = 0     # slot-buffer rows the EP layer's
+        self.ep_rows_filled = 0       #   expert FFN ran / of them, kept
+        #   pairs of live rows (their ratio: the buffers' fill share)
         self.cow_copies = 0           # copy-on-write tail clones
         self.host_syncs = 0           # blocking host waits on device data
         #   (sync loop: one per decode round / final prefill chunk; the
@@ -685,6 +688,8 @@ class ServingRuntime:
             "decode_round_ms": pct(self.decode_round_s),
             "ttft_ms": pct(self.ttft_s),
             "ep_rows_dropped": self.ep_rows_dropped,
+            "ep_rows_computed": self.ep_rows_computed,
+            "ep_rows_filled": self.ep_rows_filled,
         }
 
     # ------------------------------------------------------------------
@@ -1268,10 +1273,13 @@ class ServingRuntime:
 
     def _ingest(self, mstats) -> None:
         """Feed a round's gating statistics to the engine and add the EP
-        layer's capacity drops to ``ep_rows_dropped``."""
+        layer's row counters (over its layers) to ``ep_rows_*``."""
         self.engine._ingest(mstats)
         if mstats is not None and "dropped" in mstats:
             self.ep_rows_dropped += int(np.asarray(mstats["dropped"]).sum())
+            self.ep_rows_computed += int(
+                np.asarray(mstats["rows_computed"]).sum())
+            self.ep_rows_filled += int(np.asarray(mstats["rows_filled"]).sum())
 
     @staticmethod
     def _round_local_frac(mstats) -> float | None:

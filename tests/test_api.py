@@ -443,6 +443,8 @@ def _bench_doc():
                 "decode_round_ms": {"p50": 3.5, "p99": 9.0},
                 "ttft_ms": {"p50": 120.0, "p99": 250.0},
                 "ep_rows_dropped": 0,
+                "ep_rows_computed": 2048,
+                "ep_rows_filled": 512,
             },
             "faults": {
                 "injected": 1,

@@ -10,9 +10,9 @@ from pathlib import Path
 SCRIPTS = Path(__file__).parent / "md_scripts"
 
 
-def run_script(name: str, timeout: int = 560):
+def run_script(name: str, timeout: int = 560, devices: int = 8):
     env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = str(Path(__file__).parent.parent / "src")
     r = subprocess.run([sys.executable, str(SCRIPTS / name)], env=env,
                        capture_output=True, text=True, timeout=timeout)
@@ -37,4 +37,9 @@ def test_placement_quality_affects_local_ratio():
 
 def test_layout_equivalence():
     out = run_script("layout_equivalence.py")
+    assert "ALL OK" in out
+
+
+def test_ep_capacity_clamp_is_dropless_on_four_devices():
+    out = run_script("ep_capacity_clamp.py", devices=4)
     assert "ALL OK" in out

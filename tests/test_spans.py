@@ -1,6 +1,6 @@
 """The serving tick's wall-clock instrumentation: ``serve.*`` host spans on
 the profiler's host plane, named scopes in the compiled steps, the
-handles' ``wall`` stamps, and the runtime's prefill-token and EP-drop
+handles' ``wall`` stamps, and the runtime's prefill-token and EP row
 counters."""
 
 import re
@@ -17,6 +17,7 @@ from repro.launch.serve import build_controller
 from repro.models import moe as M
 from repro.models import transformer as tr
 from repro.serving.api import Request
+from repro.serving.cluster import EdgeCluster
 from repro.serving.engine import ServingEngine
 from repro.serving.obs import SpanKind
 from repro.serving.runtime import ServingRuntime
@@ -145,7 +146,8 @@ def test_prefill_tokens_count_every_prompt_token(engine, src):
 @pytest.mark.parametrize("mode", ["decode", "chunk"])
 def test_ep_layer_counts_its_capacity_drops(mode):
     """With one EP rank every expert has one slot of ``C2`` rows, so the
-    drops are exactly each expert's (row, expert) pairs beyond ``C2``."""
+    drops are exactly each expert's (row, expert) pairs beyond ``C2``: a
+    capacity below what the rows can fill is used as set, not clamped."""
     mesh = make_test_mesh(1, 1)
     spec = M.EPSpec.build(mesh, CFG, ep_axes=("model",),
                           slots=CFG.num_experts, capacity=4096,
@@ -157,6 +159,8 @@ def test_ep_layer_counts_its_capacity_drops(mode):
                            mode=mode)
     n_e = np.asarray(st["counts"])
     assert int(st["dropped"]) == int(np.maximum(n_e - 2, 0).sum()) > 0
+    assert int(st["rows_filled"]) == int(np.minimum(n_e, 2).sum())
+    assert int(st["rows_computed"]) == spec.slots * 2
 
 
 def test_runtime_sums_ep_drops_at_drain(src):
@@ -167,3 +171,36 @@ def test_runtime_sums_ep_drops_at_drain(src):
     rtm.run()
     assert rtm.ep_rows_dropped > 0
     assert rtm.perf_metrics()["ep_rows_dropped"] == rtm.ep_rows_dropped
+
+
+def test_runtime_counts_the_ep_rows_it_computes(engine, src):
+    """Every call computes S x C2 slot rows a layer, C2 clamped to its own
+    rows: 8 for a decode batch of at most 2 (one sublane tile), a chunk's
+    batch x block_size rows; the live pairs fill part of them."""
+    rtm = _warm(engine, prefix_cache=False)
+    for r in _requests(src, 3):
+        rtm.enqueue(r)
+    rtm.run()
+    S, (_, layers) = engine.rt.ep_spec.slots, CFG.layer_pattern()
+    assert rtm.ep_rows_computed == layers * S * (
+        8 * rtm.rounds + rtm.block_size * rtm.prefill_rows)
+    assert (CFG.top_k * layers * rtm.prefill_tokens < rtm.ep_rows_filled
+            <= rtm.ep_rows_computed)
+    perf = rtm.perf_metrics()
+    assert perf["ep_rows_computed"] == rtm.ep_rows_computed
+    assert perf["ep_rows_filled"] == rtm.ep_rows_filled
+
+
+def test_cluster_sums_the_ep_row_counters(engine, src):
+    ec = EdgeCluster("runtime", engine=engine, n_servers=2,
+                     shared_runtime=False,
+                     runtime_opts=dict(max_slots=2, block_size=8))
+    for k, r in enumerate(_requests(src, 4)):
+        ec.submit(Request(prompt=r.prompt, max_new_tokens=r.max_new_tokens,
+                          origin=k % 2))
+    ec.run()
+    perf = ec.metrics()["perf"]
+    for key in ("ep_rows_dropped", "ep_rows_computed", "ep_rows_filled"):
+        assert perf[key] == sum(getattr(r, key)
+                                for r in ec.backend.runtimes)
+    assert 0 < perf["ep_rows_filled"] <= perf["ep_rows_computed"]
