@@ -1,5 +1,5 @@
-"""Plain float32 reference of the served model, and its lower-precision
-control.
+"""Plain float32 reference of the Mixtral-style decoder
+(``bench/arch/mixtral.py``), and its lower-precision control.
 
 A Mixtral-style decoder written out in ``jax.numpy`` from the published
 description (RMSNorm, rotary embeddings on the two halves of each head,
@@ -9,9 +9,8 @@ the chosen experts, SwiGLU experts), with every matmul at
 weights from the same seed through ``bench.weights``, one layer at a time,
 so that it fits beside nothing else on one chip.
 
-The control is the same forward with every weight matmul computed in
-float8 (e4m3, scaled per row of the activations and per column of the
-weights): the step below bfloat16 that would tempt a later change.
+The control is the same forward with every weight matmul through
+``bench.compare.matmul``'s float8 rule.
 """
 
 from __future__ import annotations
@@ -23,23 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights as W
-
-HI = jax.lax.Precision.HIGHEST
-F8 = jnp.float8_e4m3fn
-F8_MAX = 448.0
-
-
-def _mm(x, w, quant: bool):
-    """x [..., k] @ w [k, n] in float32, or through float8 for the control."""
-    x = x.astype(jnp.float32)
-    w = w.astype(jnp.float32)
-    if not quant:
-        return jnp.matmul(x, w, precision=HI)
-    xs = jnp.max(jnp.abs(x), axis=-1, keepdims=True) / F8_MAX + 1e-30
-    ws = jnp.max(jnp.abs(w), axis=0, keepdims=True) / F8_MAX + 1e-30
-    xq = (x / xs).astype(F8).astype(jnp.float32)
-    wq = (w / ws).astype(F8).astype(jnp.float32)
-    return jnp.matmul(xq, wq, precision=HI) * xs * ws
+from bench.compare import HI
+from bench.compare import matmul as _mm
 
 
 def _rms(x, scale, eps):
@@ -57,7 +41,7 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
 
-def _attention(h, lw, dims: W.Dims, quant: bool):
+def _attention(h, lw, dims, quant: bool):
     """Causal GQA self-attention of one sequence h [L, d]."""
     L = h.shape[0]
     x = _rms(h, lw["attn_norm"], dims.eps)
@@ -76,7 +60,7 @@ def _attention(h, lw, dims: W.Dims, quant: bool):
     return _mm(o.reshape(L, dims.heads * dims.hd), lw["wo"], quant)
 
 
-def _moe(h, lw, dims: W.Dims, quant: bool):
+def _moe(h, lw, dims, quant: bool):
     """Top-k softmax routing over every expert, computed densely. Also
     returns each token's routing margin: how far the k-th expert's
     probability lies above the next one's."""
@@ -97,7 +81,7 @@ def _moe(h, lw, dims: W.Dims, quant: bool):
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
-def _layer(h, lw, dims: W.Dims, quant: bool):
+def _layer(h, lw, dims, quant: bool):
     """One decoder layer over a batch of padded sequences h [K, L, d]
     (padding sits after each sequence, so causality keeps it out); also
     the routing margin of every position [K * L]."""
@@ -109,22 +93,22 @@ def _layer(h, lw, dims: W.Dims, quant: bool):
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
-def _gen_layer(key, dims: W.Dims, layer):
+def _gen_layer(key, dims, layer):
     return W.layer_leaves(key, dims, layer)
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
-def _gen_head(key, dims: W.Dims):
+def _gen_head(key, dims):
     return {n: W.leaf(key, dims, n, 0) for n in ("embed", "final_norm", "lm_head")}
 
 
 @functools.partial(jax.jit, static_argnums=(4, 5))
-def _logits(h, rows, final_norm, lm_head, dims: W.Dims, quant: bool):
+def _logits(h, rows, final_norm, lm_head, dims, quant: bool):
     x = _rms(h.reshape(-1, h.shape[-1])[rows], final_norm, dims.eps)
     return _mm(x, lm_head, quant)
 
 
-def forward_logits(dims: W.Dims, seed: int, seqs, length: int, rows: int,
+def forward_logits(dims, seed: int, seqs, length: int, rows: int,
                    control: bool = False, device=None):
     """Logits of the positions that predict each served token.
 
@@ -170,10 +154,3 @@ def forward_logits(dims: W.Dims, seed: int, seqs, length: int, rows: int,
                                  dims, True))[:n]
     return ref, ctl, np.asarray(margin)[np.asarray(idx)]
 
-
-def gaps(ref: np.ndarray, tokens: np.ndarray) -> np.ndarray:
-    """How far each token's reference logit lies below the reference's
-    best, in standard deviations of the reference's logits at that step
-    (so that the reading does not scale with the width of the model)."""
-    best = ref.max(axis=-1)
-    return (best - ref[np.arange(len(tokens)), tokens]) / ref.std(axis=-1)

@@ -3,13 +3,14 @@ seed into timed requests.
 
 The sampling follows ``repro.serving.workload.WorkloadStream`` (clipped
 lognormal prompt and output lengths, Poisson arrivals, Zipf-skewed
-origins, a token profile per origin), with one change: the sizes and the
-gaps between arrivals of each segment of a run (the warm traffic, then
-the measured window) are drawn once from the traffic file's own
-``shape_seed``, and the run's seed only reorders them within the segment
-and draws the tokens. Every seed then offers the same work to the window
-in another order: a Poisson stream conditioned on its count in each
-segment.
+origins, a token profile per origin), with one change: the arrivals of
+each segment of a run (the warm traffic, then the measured window), their
+times, prompt and output lengths and origins, are drawn once from the
+traffic file's own ``shape_seed``, and the run's seed draws only the
+tokens. Every seed then offers the window the same work on the same
+timeline (a Poisson stream conditioned on its count in each segment), so
+that what a run measures does not swing with where a seed happens to put
+the long prompts.
 """
 
 from __future__ import annotations
@@ -77,7 +78,8 @@ def stream(traffic: dict, seed: int, warm_s: float, window_s: float,
            vocab: int) -> list[Timed]:
     """The requests of a run, in arrival order: the warm segment's in
     ``[0, warm_s)``, then the window's in ``[warm_s, warm_s + window_s)``.
-    Each segment offers its fixed set in the order the seed draws."""
+    Each segment offers its fixed set in the order it was drawn; the seed
+    draws each prompt's tokens."""
     rng = np.random.default_rng(seed)
     n_org = int(traffic.get("origins", 1))
     profiles = [_token_profile(traffic, o, vocab) for o in range(n_org)]
@@ -85,14 +87,14 @@ def stream(traffic: dict, seed: int, warm_s: float, window_s: float,
     for segment, (t0, seconds) in enumerate(((0.0, warm_s), (warm_s, window_s))):
         fs = fixed_set(traffic, seconds, segment)
         n = len(fs["prompts"])
-        at = t0 + np.cumsum(fs["gaps"][rng.permutation(n + 1)])[:n]
-        for i, j in enumerate(rng.permutation(n)):
+        at = t0 + np.cumsum(fs["gaps"])[:n]
+        for j in range(n):
             org = int(fs["origins"][j])
             p = profiles[org]
             size = int(fs["prompts"][j])
             toks = (rng.integers(0, vocab, size) if p is None
                     else rng.choice(vocab, size, p=p))
-            out.append(Timed(at=float(at[i]), prompt=toks.astype(np.int32),
+            out.append(Timed(at=float(at[j]), prompt=toks.astype(np.int32),
                              max_new_tokens=int(fs["outputs"][j]),
                              origin=org if n_org > 1 else None))
     return out

@@ -4,7 +4,11 @@ served tokens against the reference, and report.
 Everything that belongs to one configuration, traffic mix or metric is a
 file found by name: ``BENCHMARK.json`` names the cell, the cell names its
 configuration file and its traffic file (``bench/traffic/<traffic>.json``),
-and each metric is read by ``bench/metrics/<metric>.py``.
+and each metric is read by ``bench/metrics/<metric>.py``. The configuration
+file names its architecture module (``"arch"``: sizes, weights, the
+program's model config and parameter tree, and the needed work of a step;
+``bench/arch/mixtral.py`` says what it holds) and its float32 reference
+(``"reference"``: ``forward_logits``), so this file knows no model.
 """
 
 from __future__ import annotations
@@ -20,9 +24,8 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import chip, generator, openloop, stats
-from bench import flops as F
-from bench import weights as W
+from bench import chip, compare, generator, openloop, stats
+from bench import program_spans as PS
 from bench.context import Context
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -46,13 +49,26 @@ def load_cell(workload: str, root: Path = ROOT):
     return bm, cell, conf, traffic
 
 
+def load_module(path, root: Path = ROOT):
+    """The Python file at ``path`` (relative to ``root``) as a module,
+    loaded once per process."""
+    path = (root / path).resolve()
+    name = "bench_file_" + "".join(ch if ch.isalnum() else "_"
+                                   for ch in str(path))
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
+
+
 def metric_reader(name: str, root: Path = ROOT):
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(Path("bench") / "metrics" / f"{name}.py", root).read
 
 
 def metrics_of(bm: dict, cell: str, trace: bool) -> list[dict]:
@@ -68,24 +84,29 @@ def metrics_of(bm: dict, cell: str, trace: bool) -> list[dict]:
                 else m["moves"] in moved)]
 
 
+# where the program's serving steps keep what the work counts read:
+# {step: {"args": number of arguments, "stats": position of the MoE
+# statistics in the output, "read": {name: (position, dtype, ndim)}}}. An
+# architecture whose step takes other arguments brings its own ``LAYOUT``
+DEFAULT_LAYOUT = {
+    "dec": {"args": 11, "stats": 3,
+            "read": {"pos": (4, "int32", 1), "mask": (7, "float32", 1)}},
+    "chunk": {"args": 14, "stats": 4,
+              "read": {"offsets": (7, "int32", 1), "mask": (10, "float32", 2)}},
+}
+
+
 class _Recorder:
     """Stands in for one warmed serving executable while the window is
     traced, keeping the small host-made inputs and the gating counts of
-    each call (device arrays, read once the window has closed)."""
+    each call (device arrays, read once the window has closed). A step
+    whose signature moves fails the run rather than feeding other
+    arguments to the counts."""
 
-    def __init__(self, exe, kind: str, log: dict):
-        self.exe, self.kind, self.log = exe, kind, log
-
-    # where each step keeps what is read: (number of arguments, the
-    # positions and kinds of the position and mask arguments, the position
-    # of the MoE statistics in the output). A step whose signature moves
-    # fails the run rather than feeding other arguments to the counts
-    LAYOUT = {"dec": (11, (4, "int32", 1), (7, "float32", 1), 3),
-              "chunk": (14, (7, "int32", 1), (10, "float32", 2), 4)}
-
-    def __init__(self, exe, key: tuple, log: dict):
+    def __init__(self, exe, key: tuple, log: dict, layout: dict = None):
         self.exe, self.kind, self.log = exe, key[0], log
         self.width, self.block_size = key[3], key[1]
+        self.layout = (layout or DEFAULT_LAYOUT)[self.kind]
 
     def _take(self, args, at):
         i, dtype, ndim = at
@@ -95,48 +116,48 @@ class _Recorder:
             raise RuntimeError(
                 f"bench: the {self.kind} step's argument {i} is "
                 f"{a.dtype}{list(a.shape)}, not {dtype}{list(want)}: the "
-                f"program's step signature changed; update the recorder")
+                f"program's step signature changed; update the layout")
         return a
 
     def __call__(self, *args):
         out = self.exe(*args)
         if self.log["on"]:
-            n, pos, mask, stats = self.LAYOUT[self.kind]
+            n, stats = self.layout["args"], self.layout["stats"]
             if len(args) != n or not isinstance(out[stats], dict):
                 raise RuntimeError(
                     f"bench: the {self.kind} step takes {len(args)} arguments "
                     f"(expected {n}) or returns no statistics at {stats}: the "
-                    f"program's step signature changed; update the recorder")
+                    f"program's step signature changed; update the layout")
             counts = out[stats]["counts"]
             if counts.shape[-1] != self.log["experts"]:
                 raise RuntimeError(
                     f"bench: the {self.kind} step's gating counts end in "
                     f"{counts.shape[-1]}, not the {self.log['experts']} experts")
+            call = {k: self._take(args, at)
+                    for k, at in self.layout["read"].items()}
             self.log["decode" if self.kind == "dec" else "chunk"].append(
-                (self._take(args, pos), self._take(args, mask), counts))
+                {**call, "counts": counts})
         return out
 
 
-def _record_work(engine, log: dict) -> None:
+def _record_work(engine, log: dict, layout: dict = None) -> list[str]:
+    """Wrap the engine's warmed steps in recorders; returns their HLO
+    texts, which name the program's scopes in a trace."""
+    hlo = []
     for key, exe in list(engine._compiled.items()):
         if key[0] in ("dec", "chunk"):
-            engine._compiled[key] = _Recorder(exe, key, log)
+            hlo.append(exe.as_text())
+            engine._compiled[key] = _Recorder(exe, key, log, layout)
+    return hlo
 
 
-def _work(log: dict, dims: W.Dims, block_size: int) -> dict:
-    out = {"decode": [], "chunk": []}
-    for pos, mask, counts in log["decode"]:
-        live = np.asarray(mask) > 0
-        touched = (np.asarray(counts) > 0).sum(-1)
-        out["decode"].append(F.decode_step(dims, np.asarray(pos)[live] + 1,
-                                           touched))
-    for offs, mask, counts in log["chunk"]:
-        valid = np.asarray(mask).sum(-1).astype(int)
-        live = valid > 0
-        touched = (np.asarray(counts) > 0).sum(-1)
-        out["chunk"].append(F.prefill_chunk(dims, np.asarray(offs)[live],
-                                            valid[live], touched, block_size))
-    return out
+def _work(log: dict, arch, dims, block_size: int) -> dict:
+    """The architecture's needed work of every recorded call."""
+    return {kind: [arch.work(dims, kind,
+                             {k: np.asarray(v) for k, v in call.items()},
+                             block_size)
+                   for call in log[kind]]
+            for kind in ("decode", "chunk")}
 
 
 def _compile_counter() -> dict:
@@ -169,20 +190,18 @@ def _sample(records, k: int, seed: int) -> list:
     return [done[first]] + [done[i] for i in sorted(pick)]
 
 
-def _check(conf, traffic, dims, seed, seqs, control, log):
-    """The served tokens of ``seqs`` against the float32 reference:
-    ``({name: {"value", "limit"}}, control readings or None)``."""
-    from bench import reference as REF
-
+def _check(conf, traffic, dims, seed, seqs, control, log, root=ROOT):
+    """The served tokens of ``seqs`` against the configuration's float32
+    reference: ``({name: {"value", "limit"}}, control readings or None)``."""
     if not seqs:
         return {}, None
     t_ref = time.perf_counter()
     length = -(-(traffic["prompt_len"][3] + traffic["output_len"][3]) // 128) * 128
     rows = int(traffic["check"]["requests"]) * int(traffic["output_len"][3])
-    ref, ctl, margin = REF.forward_logits(dims, seed, seqs, length, rows,
-                                          control=control)
+    ref, ctl, margin = load_module(conf["reference"], root).forward_logits(
+        dims, seed, seqs, length, rows, control=control)
     served = np.concatenate([s for _, s in seqs])
-    g = REF.gaps(ref, served)
+    g = compare.gaps(ref, served)
     far = g > 0.25
     log(f"gaps: mean {g.mean():.6g}, p50 {np.percentile(g, 50):.6g}, "
         f"p90 {np.percentile(g, 90):.6g}, p99 {np.percentile(g, 99):.6g}, "
@@ -190,33 +209,18 @@ def _check(conf, traffic, dims, seed, seqs, control, log):
         f"{int(far.sum())} of {len(g)} above 0.25, the reference's routing "
         f"margin there {np.round(np.sort(margin[far]), 5).tolist()} "
         f"against a median of {np.median(margin):.5g}", file=sys.stderr)
-    checks = _limited(conf, g)
+    checks = compare.limited(conf, g)
     control_out = None
     if ctl is not None:
-        gc_ = REF.gaps(ref, ctl.argmax(-1))
+        gc_ = compare.gaps(ref, ctl.argmax(-1))
         log(f"control gaps: mean {gc_.mean():.6g}, max {gc_.max():.6g}",
             file=sys.stderr)
-        control_checks = _limited(conf, gc_)
-        control_out = {"correct": _passes(control_checks),
+        control_checks = compare.limited(conf, gc_)
+        control_out = {"correct": compare.passes(control_checks),
                        "checks": control_checks}
     log(f"reference: {len(seqs)} requests, {len(served)} served tokens, "
         f"{time.perf_counter() - t_ref:.3f} s", file=sys.stderr)
     return checks, control_out
-
-
-def _limited(conf: dict, gaps: np.ndarray) -> dict:
-    """Each number the configuration limits, read from per-token gaps,
-    beside its limit."""
-    readings = {"max_gap": float(gaps.max()), "mean_gap": float(gaps.mean())}
-    return {k: {"value": readings[k], "limit": lim}
-            for k, lim in conf["limits"].items()}
-
-
-def _passes(checks: dict) -> bool:
-    """The decision on ``correct``: every number within its limit."""
-    return bool(checks) and all(
-        np.isfinite(c["value"]) and c["value"] <= c["limit"]
-        for c in checks.values())
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool, *,
@@ -239,29 +243,32 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     d0 = devices[0]
     peaks = chip.peaks(d0.device_kind) if d0.platform == "tpu" else None
     compiles = _compile_counter()
-    dims = W.Dims.from_config(conf)
+    arch = load_module(conf["arch"], root)
+    dims = arch.dims(conf)
+    cfg = arch.model_config(conf, cell["config"])
     serving = traffic["serving"]
     max_len = generator.max_len(traffic)
     warm = float(traffic["warm_seconds"])
-    requests = generator.stream(traffic, seed, warm, seconds, dims.vocab)
+    requests = generator.stream(traffic, seed, warm, seconds, cfg.vocab_size)
 
     from bench import system as S
 
-    system = S.build(conf, cell["config"], serving, max_len, seed, n_chips)
+    system = S.build(arch, dims, cfg, serving, max_len, seed, n_chips)
     log(f"built in {time.perf_counter() - t_start:.3f} s since start; "
         f"{sum(r.executables_compiled for r in system.runtimes)} warmed "
         f"executables in {sum(r.warmup_seconds for r in system.runtimes):.3f} s; "
         f"bytes in use {[(d.memory_stats() or {}).get('bytes_in_use') for d in devices]}",
         file=sys.stderr)
     work_log = {"on": False, "decode": [], "chunk": [],
-                "experts": dims.experts}
-    if trace:
-        _record_work(system.engine, work_log)
+                "experts": cfg.num_experts}
+    hlo = (_record_work(system.engine, work_log, getattr(arch, "LAYOUT", None))
+           if trace else [])
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     snap = {}
 
     def at_start():
         snap["c0"] = system.counters()
+        snap["p0"] = PS.counters(system.runtimes)
         snap["compiles"] = compiles["n"]
         snap["queued0"] = sum(len(r.queue) for r in system.runtimes)
 
@@ -274,6 +281,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     def at_end():
         work_log["on"] = False
         snap["c1"] = system.counters()
+        snap["p1"] = PS.counters(system.runtimes)
         snap["queued1"] = sum(len(r.queue) for r in system.runtimes)
         snap["compiled"] = sorted(set(compiles["names"][snap["compiles"]:]))
         snap["compiles"] = compiles["n"] - snap["compiles"]
@@ -301,24 +309,32 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         f"queued at start {snap['queued0']}, at end {snap['queued1']}",
         file=sys.stderr)
 
-    summary = None
-    if trace and d0.platform == "tpu":
-        from bench import reduce_trace as R
+    summary = program = None
+    if trace:
+        program_trace = None
+        if d0.platform == "tpu":
+            from bench import reduce_trace as R
 
-        events = R.load_xplane(R.find_xplane(trace_dir))
-        summary = R.summarize(events)
-        log(f"trace: {summary.window_s:.3f} s, busy {summary.busy_s:.3f} s, "
-            f"modules {summary.modules}, collectives {summary.collective_s}",
-            file=sys.stderr)
-    if trace_dir:
+            xplane = R.find_xplane(trace_dir)
+            summary = R.summarize(R.load_xplane(xplane))
+            program_trace = PS.load_xplane(xplane)
+            log(f"trace: {summary.window_s:.3f} s, busy {summary.busy_s:.3f} s, "
+                f"modules {summary.modules}, collectives {summary.collective_s}",
+                file=sys.stderr)
+        program = PS.reading(program_trace, records, w0, w1, snap["p0"],
+                             snap["p1"], hlo)
+        log(f"program: scopes {program.scope_ms}, scoped share "
+            f"{program.scoped_share}, counters {program.counters}, gaps "
+            f"{program.gaps}", file=sys.stderr)
         shutil.rmtree(trace_dir, ignore_errors=True)
-    work = _work(work_log, dims, serving["block_size"]) if trace else None
+    work = (_work(work_log, arch, dims, serving["block_size"]) if trace
+            else None)
     if work:
         log(f"recorded calls: decode {len(work['decode'])}, "
             f"chunk {len(work['chunk'])}", file=sys.stderr)
 
     ctx = Context(window=win, counters=counters, chips=n_chips, peaks=peaks,
-                  trace=summary, work=work)
+                  trace=summary, work=work, program=program)
     metrics = {}
     for m in metrics_of(bm, workload, trace):
         if m["name"] == "setup_s":
@@ -335,12 +351,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     sample = _sample(records, int(traffic["check"]["requests"]), seed)
     seqs = [(np.asarray(r.handle.request.prompt, np.int32),
              openloop.tokens_of(r)) for r in sample]
-    del system, records, sample, work_log
+    del system, records, sample, work_log, program, ctx
     gc.collect()
     checks, control_out = _check(conf, traffic, dims, seed, seqs, control,
-                                 log)
+                                 log, root)
     result = {
-        "correct": _passes(checks),
+        "correct": compare.passes(checks),
         "attempted": win.attempted,
         "failed": win.failed,
         "metrics": metrics,

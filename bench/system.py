@@ -6,7 +6,8 @@ One chip: ``ServingRuntime`` over an EP engine with a DanceMoE
 the ``EdgeCluster`` runtime backend with one EP rank per chip (as
 ``chip_smoke.py --four-chips``). ``build_engine`` takes no weights, so the
 few lines that assemble the engine are mirrored here to hand it the
-benchmark's own.
+benchmark's own, which the configuration's architecture module makes
+(``model_config`` and ``params``; see ``bench/arch/mixtral.py``).
 """
 
 from __future__ import annotations
@@ -14,55 +15,8 @@ from __future__ import annotations
 import dataclasses
 
 import jax
-from jax.sharding import NamedSharding
-from jax.sharding import PartitionSpec as P
 
 from bench import weights as W
-
-
-def model_config(conf: dict, name: str):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-
-    d = W.Dims.from_config(conf)
-    return ModelConfig(
-        name=name, family="moe", num_layers=d.layers, d_model=d.d,
-        num_heads=d.heads, num_kv_heads=d.kv_heads, d_ff=d.f,
-        vocab_size=d.vocab, head_dim=d.hd, num_experts=d.experts,
-        top_k=d.top_k, moe_every=1, rope_theta=d.rope_theta,
-        norm_eps=d.eps, source=conf["source"],
-    )
-
-
-def _program_params(dims, key, mesh):
-    """The program's parameter tree (dense master experts), made in one
-    jitted call from ``key``; expert weights sharded over ``model``."""
-
-    def make(k):
-        groups = {
-            "b0": {
-                "norm": W.stacked(k, dims, "attn_norm"),
-                **{n: W.stacked(k, dims, n) for n in ("wq", "wk", "wv", "wo")},
-            },
-            "b1": {
-                "norm": W.stacked(k, dims, "moe_norm"),
-                **{n: W.stacked(k, dims, n) for n in ("router", "w1", "w3", "w2")},
-            },
-        }
-        return {
-            "embed": W.leaf(k, dims, "embed", 0),
-            "final_norm": W.leaf(k, dims, "final_norm", 0),
-            "lm_head": W.leaf(k, dims, "lm_head", 0),
-            "groups": groups,
-        }
-
-    rep = NamedSharding(mesh, P())
-    experts = NamedSharding(mesh, P(None, "model"))
-    shapes = jax.eval_shape(make, key)
-    out = jax.tree.map(lambda _: rep, shapes)
-    for w in ("w1", "w3", "w2"):
-        out["groups"]["b1"][w] = experts
-    return jax.jit(make, out_shardings=out)(key), experts
 
 
 @dataclasses.dataclass
@@ -101,18 +55,17 @@ class System:
         return out
 
 
-def build(conf: dict, name: str, serving: dict, max_len: int, seed: int,
+def build(arch, dims, cfg, serving: dict, max_len: int, seed: int,
           chips: int) -> System:
-    """Engine, controller and runtime (or cluster) for one cell; the AOT
-    warmup ladder compiles (or loads from the cache) here."""
+    """Engine, controller and runtime (or cluster) for one cell of the
+    architecture module ``arch`` (its ``dims`` and the program's ``cfg``);
+    the AOT warmup ladder compiles (or loads from the cache) here."""
     from repro.launch.mesh import make_test_mesh
     from repro.launch.serve import build_controller, ep_capacities, step_rows
     from repro.models import moe as M
     from repro.models import transformer as tr
     from repro.serving.engine import ServingEngine
 
-    cfg = model_config(conf, name)
-    dims = W.Dims.from_config(conf)
     mesh = make_test_mesh(1, chips)
     slots, bs = serving["max_slots"], serving["block_size"]
     rows = step_rows(max_len=max_len, slots=slots, block_size=bs)
@@ -121,7 +74,7 @@ def build(conf: dict, name: str, serving: dict, max_len: int, seed: int,
                           slot_capacity=C2)
     rt = tr.Runtime(cfg=cfg, mesh=mesh, moe_impl="ep", ep_spec=spec,
                     dtype=W.DTYPE)
-    params, experts = _program_params(dims, W.base_key(seed), mesh)
+    params, experts = arch.params(W.base_key(seed), dims, mesh)
     expect = jax.eval_shape(
         lambda k: tr.init_params(dataclasses.replace(rt, moe_impl="dense",
                                                      ep_spec=None), k),

@@ -34,8 +34,8 @@ def test_generator_replays_from_seed():
 
 
 def test_seeds_reorder_one_set_of_sizes():
-    """Every seed offers each segment the same gaps and lengths, in
-    another order, so the window's work does not depend on the seed."""
+    """Every seed offers each segment the traffic file's one set of gaps
+    and lengths, so the window's work does not depend on the seed."""
     t = _traffic()
     runs = [generator.stream(t, seed, 20.0, 40.0, 32000) for seed in (1, 2)]
     for seg, (lo, hi) in enumerate(((0.0, 20.0), (20.0, 60.0))):
@@ -46,9 +46,23 @@ def test_seeds_reorder_one_set_of_sizes():
                 == Counter(zip(fs["prompts"], fs["outputs"]))
             gaps = np.diff([lo] + [x.at for x in inside])
             assert set(np.round(gaps, 6)) <= set(np.round(fs["gaps"], 6))
-    assert [x.at for x in runs[0]] != [x.at for x in runs[1]]
     lo, hi = t["prompt_len"][2:]
     assert all(lo <= len(x.prompt) <= hi for x in runs[0])
+
+
+@pytest.mark.parametrize("origins", [1, 4])
+def test_every_seed_offers_one_timeline(origins):
+    """Two seeds give the same arrival times, prompt and output lengths
+    and origins, in the same order, and other tokens: the seed draws the
+    tokens alone."""
+    t = {**_traffic(), "origins": origins, "origin_skew": 1.0,
+         "origin_tokens": {"zipf": 1.1} if origins > 1 else None}
+    a, b = (generator.stream(t, seed, 20.0, 51.0, 32000)
+            for seed in (2**40 + 3, 8000001601))
+    assert len(a) == len(b) > 100
+    assert [(x.at, len(x.prompt), x.max_new_tokens, x.origin) for x in a] \
+        == [(x.at, len(x.prompt), x.max_new_tokens, x.origin) for x in b]
+    assert all(not np.array_equal(x.prompt, y.prompt) for x, y in zip(a, b))
 
 
 def test_origins_draw_their_own_tokens():
@@ -107,6 +121,11 @@ def test_benchmark_names_only_files_that_exist():
     for c in bm["configs"]:
         conf = json.loads((ROOT / c["file"]).read_text())
         assert set(conf["limits"]) == {"mean_gap"}
+        arch = harness.load_module(conf["arch"])
+        for attr in ("dims", "model_config", "params", "work", "TINY"):
+            assert callable(getattr(arch, attr)) or attr == "TINY"
+        assert arch.dims(conf).layers == conf["num_hidden_layers"]
+        assert callable(harness.load_module(conf["reference"]).forward_logits)
     for w in bm["workloads"]:
         t = json.loads((ROOT / "bench" / "traffic" / f"{w['traffic']}.json")
                        .read_text())
@@ -145,12 +164,12 @@ def test_run_refuses_without_the_program(tmp_path):
 def _step_call(kind, width=4, block_size=8, experts=8):
     """Arguments and outputs shaped as the program's warmed step takes and
     gives them (the unread ones as placeholders)."""
-    n, pos, mask, stats = harness._Recorder.LAYOUT[kind]
-    args = [None] * n
-    args[pos[0]] = np.zeros((width, block_size)[:pos[2]], np.int32)
-    args[mask[0]] = np.ones((width, block_size)[:mask[2]], np.float32)
-    out = [None] * (stats + 1)
-    out[stats] = {"counts": np.ones((2, experts), np.float32)}
+    lay = harness.DEFAULT_LAYOUT[kind]
+    args = [None] * lay["args"]
+    for i, dtype, ndim in lay["read"].values():
+        args[i] = np.full((width, block_size)[:ndim], 1, dtype)
+    out = [None] * (lay["stats"] + 1)
+    out[lay["stats"]] = {"counts": np.ones((2, experts), np.float32)}
     return args, tuple(out)
 
 
@@ -161,7 +180,9 @@ def test_recorder_reads_only_the_step_it_knows(kind, fault):
     """The traced run's work counts read the step's positions, masks and
     gating counts by place; a step whose signature moved fails the run."""
     args, out = _step_call(kind)
-    n, pos, mask, stats = harness._Recorder.LAYOUT[kind]
+    lay = harness.DEFAULT_LAYOUT[kind]
+    pos = lay["read"]["pos" if kind == "dec" else "offsets"]
+    mask = lay["read"]["mask"]
     if fault == "one argument more":
         args.append(None)
     elif fault == "mask in int32":
@@ -169,13 +190,14 @@ def test_recorder_reads_only_the_step_it_knows(kind, fault):
     elif fault == "positions of another width":
         args[pos[0]] = args[pos[0]][:2]
     elif fault == "no counts":
-        out = out[:stats] + (None,)
+        out = out[:lay["stats"]] + (None,)
     log = {"on": True, "decode": [], "chunk": [], "experts": 8}
     rec = harness._Recorder(lambda *a: out, (kind, 8, 81, 4, False), log)
     if fault is None:
         assert rec(*args) is out
         (got,) = log["decode" if kind == "dec" else "chunk"]
-        assert got[0] is args[pos[0]] and got[1] is args[mask[0]]
+        assert got[next(iter(lay["read"]))] is args[pos[0]]
+        assert got["mask"] is args[mask[0]]
     else:
         with pytest.raises(RuntimeError, match="signature|statistics"):
             rec(*args)

@@ -114,6 +114,31 @@ def test_readers_by_hand():
         "prefill_wait_p90_ms": 2800.0})
 
 
+def test_scope_rooflines_by_hand():
+    """A scope's roofline: its needed work per call (the mean over the
+    recorded calls) over its device time per run of the step."""
+    trace, hlo = PS.load_json(HERE / "trace_program_synthetic.json")
+    program = PS.reading(trace, hlo=hlo)     # attn 35 ms, expert FFN 50 ms
+    calls = [{"step": {"flops": 0.0, "bytes": 0.0},
+              "attn": {"flops": f, "bytes": 1e6},
+              "moe.expert_ffn": {"flops": 1e9, "bytes": b}}
+             for f, b in ((1e9, 2e7), (3e9, 4e7))]
+    peaks = {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e9}
+    ctx = Context(window=None, counters={}, chips=1, peaks=peaks,
+                  work={"decode": calls, "chunk": []}, program=program)
+    read = {n: harness.metric_reader(n)(ctx) for n in (
+        "decode_attention_roofline", "decode_expert_ffn_roofline")}
+    assert read["decode_attention_roofline"] == {
+        "value": pytest.approx(100 * 2e-3 / 35e-3), "bound": "flops"}
+    assert read["decode_expert_ffn_roofline"] == {
+        "value": pytest.approx(100 * 30e-3 / 50e-3), "bound": "hbm"}
+    for ctx in (Context(window=None, counters={}, chips=1, peaks=peaks,
+                        work={"decode": calls}),
+                Context(window=None, counters={}, chips=1, peaks=peaks,
+                        program=program)):
+        assert harness.metric_reader("decode_attention_roofline")(ctx) is None
+
+
 @pytest.mark.parametrize("name,before", [
     ("trace_synthetic.json", (1e-4, 1.5e-4, 30.0)),
     ("trace_v5e_mixtral.json.gz", (65.732333, 45.996855, 0.0039872091)),
